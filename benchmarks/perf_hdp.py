@@ -8,6 +8,7 @@ EXPERIMENTS.md §Perf.
 """
 import argparse
 import json
+import os
 import time
 
 VARIANTS = [
@@ -396,7 +397,17 @@ def main():
                     "BENCH_hdp_serve.json" if args.serve else
                     "BENCH_hdp_fleet.json" if args.serve_fleet else
                     "BENCH_hdp_dryrun.json")
+    if not (args.stream or args.serve or args.serve_fleet):
+        # the dry-run lowers production meshes on 512 placeholder host
+        # devices; the flag must be in place before jax starts a backend.
+        os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+            os.environ.get("XLA_FLAGS", ""),
+            "--xla_force_host_platform_device_count=512",
+        ]))
     from repro import obs
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     obs.setup(trace=args.trace, metrics_path=args.metrics)
     try:
         if args.serve_fleet:

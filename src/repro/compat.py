@@ -1,63 +1,38 @@
-"""Version-compat shims for the pinned jax (0.4.37).
+"""Mesh and shard_map construction, in one place.
 
-The supported environment pins jax 0.4.37 (CPU tier-1); newer jax moved
-three APIs this codebase uses:
-
-  * ``jax.sharding.AxisType`` (+ the ``axis_types=`` kwarg on
-    ``jax.make_mesh`` / ``Mesh``) does not exist yet — meshes are always
-    "auto" in 0.4.37, so the shim accepts and drops the kwarg.
-  * ``jax.shard_map`` does not exist; the implementation lives at
-    ``jax.experimental.shard_map.shard_map`` with ``check_rep`` instead
-    of ``check_vma`` and ``auto=`` (the complement of the manual axes)
-    instead of ``axis_names=``.
-
-Policy (ROADMAP.md): all mesh/shard_map construction in this repo goes
-through this module, never through ``jax.sharding`` / ``jax.shard_map``
-directly, so a future jax bump is a one-file change.
+The repo pins jax 0.9.0 (``pyproject.toml``). Mesh and shard_map
+construction goes through this module so that call sites share one
+spelling of axis types and of the manual-axes argument.
 """
 
 from __future__ import annotations
 
-import enum
 from typing import Any, Optional, Sequence
 
 import jax
-from jax.sharding import Mesh
+import numpy as np
+from jax.sharding import AxisType, Mesh
 
-try:  # jax >= 0.5: explicit/auto/manual mesh axis types
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-
-    HAS_AXIS_TYPE = True
-except ImportError:  # pinned jax 0.4.37: every mesh axis is implicitly Auto
-    class AxisType(enum.Enum):
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-    HAS_AXIS_TYPE = False
+__all__ = ["AxisType", "axis_size", "make_mesh", "mesh_from_devices",
+           "shard_map", "single_device_mesh"]
 
 
 def make_mesh(
     axis_shapes: Sequence[int], axis_names: Sequence[str], *,
     axis_types: Optional[Sequence[Any]] = None, devices=None,
 ) -> Mesh:
-    """jax.make_mesh that tolerates the axis_types kwarg on old jax."""
-    if HAS_AXIS_TYPE and axis_types is not None:
-        return jax.make_mesh(
-            axis_shapes, axis_names, axis_types=tuple(axis_types),
-            devices=devices,
-        )
-    return jax.make_mesh(axis_shapes, axis_names, devices=devices)
+    """``jax.make_mesh``; ``axis_types`` defaults to jax's own."""
+    kw = {} if axis_types is None else {"axis_types": tuple(axis_types)}
+    return jax.make_mesh(axis_shapes, axis_names, devices=devices, **kw)
 
 
 def mesh_from_devices(
     device_array, axis_names: Sequence[str], *,
     axis_types: Optional[Sequence[Any]] = None,
 ) -> Mesh:
-    """Mesh(devices, names) that tolerates the axis_types kwarg."""
-    if HAS_AXIS_TYPE and axis_types is not None:
-        return Mesh(device_array, axis_names, axis_types=tuple(axis_types))
-    return Mesh(device_array, axis_names)
+    """``Mesh(devices, names)``; ``axis_types`` defaults to jax's own."""
+    kw = {} if axis_types is None else {"axis_types": tuple(axis_types)}
+    return Mesh(device_array, tuple(axis_names), **kw)
 
 
 def single_device_mesh(device=None,
@@ -66,56 +41,30 @@ def single_device_mesh(device=None,
                        ) -> Mesh:
     """A (1, ..., 1) mesh pinned to one device (default: devices()[0]).
 
-    The multi-device streaming conformance suite anchors the primary
-    model mesh here so tables, state and the key schedule are built on
-    the same single device at every lane count — the lane sweeps
-    (core/streaming.py) place work per-device themselves and never
-    widen this mesh.
+    The HDP trainer anchors its model mesh here so tables, state and the
+    key schedule are built on the same single device at every lane
+    count — the lane sweeps (core/streaming.py) place work per-device
+    themselves and never widen this mesh.
     """
-    import numpy as np
-
     if device is None:
         device = jax.devices()[0]
     arr = np.asarray([device]).reshape((1,) * len(axis_names))
-    return mesh_from_devices(arr, tuple(axis_names),
-                             axis_types=axis_types)
-
-
-def default_axis_types(n: int) -> tuple:
-    return (AxisType.Auto,) * n
+    return mesh_from_devices(arr, tuple(axis_names), axis_types=axis_types)
 
 
 def axis_size(axis_name) -> Any:
-    """jax.lax.axis_size (absent in 0.4.37): size of a mapped mesh axis
-    from inside a shard_map region."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
+    """Size of a mapped mesh axis from inside a shard_map region."""
+    return jax.lax.axis_size(axis_name)
 
 
 def shard_map(
     f, *, mesh: Mesh, in_specs, out_specs,
     axis_names: Optional[frozenset] = None, check_vma: bool = False,
 ):
-    """jax.shard_map front-end over either API generation.
-
-    ``axis_names`` is the NEW-style argument: the set of mesh axes that
-    are manual inside ``f`` (all axes when None). Old jax expresses the
-    same thing as ``auto`` = the complement. ``check_vma`` maps to
-    ``check_rep`` on old jax.
-    """
-    if hasattr(jax, "shard_map"):
-        kw: dict[str, Any] = {"check_vma": check_vma}
-        if axis_names is not None:
-            kw["axis_names"] = axis_names
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {"check_rep": check_vma}
+    """``jax.shard_map``; ``axis_names`` is the set of mesh axes that are
+    manual inside ``f`` (all axes when None)."""
+    kw: dict[str, Any] = {"check_vma": check_vma}
     if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-    )
+        kw["axis_names"] = axis_names
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)

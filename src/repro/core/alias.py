@@ -21,12 +21,13 @@ where the previous revision also paid a full ascending ``argsort`` per
 row (the single most expensive op of the build on CPU/TPU alike).
 
 ``alias_build_row_onehot`` is the same pairing expressed with only
-comparisons, selects, one-hot reductions and cumulative sums — no sort,
+comparisons, selects, one-hot reductions and ``prefix_sum`` — no sort,
 gather, scatter or ``searchsorted`` primitives — so it lowers inside a
 Pallas TPU kernel. It is the builder the hdp_z kernel prologue
 (``alias_in_kernel``) runs per token in VMEM, and it is bitwise-equal to
-``_alias_build_row_flat`` on the same backend: binary search on a
-nondecreasing line equals its comparison count, and one-hot gathers
+``_alias_build_row_flat``: both take their cumulative lines from
+``prefix_sum`` (one order of additions on every backend), binary search
+on a nondecreasing line equals its comparison count, and one-hot gathers
 select values without arithmetic on them.
 
 Bitwise note (conformance rationale): the flat partition realizes a
@@ -54,7 +55,39 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def _normalized(p: jax.Array) -> jax.Array:
+def prefix_sum(x: jax.Array, roll=jnp.roll) -> jax.Array:
+    """Inclusive prefix sum along the last axis, with one fixed order of
+    additions: log2(n) doubling steps, ``x += shift(x, s)`` for
+    s = 1, 2, 4, ... with zeros shifted in (Hillis and Steele 1986).
+
+    Every row total and cumulative line of the z-step map goes through
+    this helper — in XLA (``roll=jnp.roll``) and inside the hdp_z kernel
+    (``roll=pltpu.roll``) — so the kernel, its oracle and the table
+    builders add the same floats in the same order on every backend.
+    ``jnp.cumsum`` gives no such promise: XLA rewrites it into blocked
+    scans whose shape depends on the length and the platform. Lane l
+    depends only on lanes <= l, so padding past the end changes no
+    earlier lane.
+    """
+    n = x.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    s = 1
+    while s < n:
+        x = x + jnp.where(lane >= s, roll(x, s, x.ndim - 1),
+                          jnp.zeros_like(x))
+        s *= 2
+    return x
+
+
+def last_lane(x: jax.Array) -> jax.Array:
+    """``x[..., -1:]`` as a one-hot lane reduction (one selected value
+    plus exact zeros), which lowers inside a Pallas TPU kernel."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.sum(jnp.where(lane == x.shape[-1] - 1, x, jnp.zeros_like(x)),
+                   axis=-1, keepdims=True)
+
+
+def _normalized(p: jax.Array, roll=jnp.roll) -> jax.Array:
     """q = p / mean(p): the alias construction's working scale, where
     "small" entries sit below 1.
 
@@ -63,12 +96,13 @@ def _normalized(p: jax.Array) -> jax.Array:
     the whole row with a NaN at the Inf entry — the resulting table
     sampled garbage without tripping any error), and all-zero rows
     (e.g. padded vocab entries, or rows that were entirely non-finite)
-    fall back to uniform. Kernel-safe: comparisons and selects only.
+    fall back to uniform. Kernel-safe: comparisons and selects only; the
+    total is the last lane of ``prefix_sum``.
     """
-    p = jnp.where(jnp.isfinite(p) & (p > 0), p, 0.0)
-    total = jnp.sum(p)
+    p = jnp.where((p > 0) & (p < jnp.inf), p, 0.0)
+    total = last_lane(prefix_sum(p, roll))
     return jnp.where(
-        total > 0, p / jnp.maximum(total, 1e-30) * p.shape[0],
+        total > 0, p / jnp.maximum(total, 1e-30) * p.shape[-1],
         jnp.ones_like(p),
     )
 
@@ -114,8 +148,8 @@ def _alias_build_row_flat(p: jax.Array) -> tuple[jax.Array, jax.Array]:
 
     d = jnp.where(small, 1.0 - q, 0.0)
     u = jnp.where(large, q - 1.0, 0.0)
-    dcum = jnp.cumsum(d)        # S: plateaus at larges
-    ucum = jnp.cumsum(u)        # U: plateaus at smalls
+    dcum = prefix_sum(d)        # S: plateaus at larges
+    ucum = prefix_sum(u)        # U: plateaus at smalls
 
     # Both monotone lines are searched at *full length*; the count of
     # larges (resp. smalls) inside the located prefix converts a
@@ -148,64 +182,88 @@ def _alias_build_row_flat(p: jax.Array) -> tuple[jax.Array, jax.Array]:
     return prob.astype(jnp.float32), alias.astype(jnp.int32)
 
 
-def alias_build_row_onehot(p: jax.Array) -> tuple[jax.Array, jax.Array]:
+def alias_build_row_onehot(
+    p: jax.Array, roll=jnp.roll
+) -> tuple[jax.Array, jax.Array]:
     """``_alias_build_row_flat`` re-expressed with Pallas-lowerable ops
-    only: comparisons, selects, cumulative sums and one-hot reductions —
-    no iota, sort, gather, scatter or ``searchsorted``.
+    only: comparisons, selects, 2-D iotas, lane rolls, transposes and
+    one-hot reductions — no sort, gather, scatter or ``searchsorted``.
+
+    ``p`` is one row, (K,) or (1, K); the outputs keep its shape. The
+    pairwise (K, K) comparisons put the index being counted on sublanes
+    and the row entry on lanes, so every count is a reduction over axis
+    0 that comes out as a (1, K) row again; ``col(x)`` broadcasts a row
+    down the columns by a transpose. Inside the hdp_z kernel ``roll`` is
+    ``pltpu.roll``.
 
     This is the builder the hdp_z kernel prologue runs per token over
     the word's W-wide support row, and the oracle side of the
     ``alias_in_kernel`` conformance tests. Bitwise-equal to
-    ``_alias_build_row_flat`` on the same backend: a binary search on a
-    nondecreasing line returns exactly its comparison count, and one-hot
-    reductions (sum of one selected value and exact zeros) reproduce
-    gathers bit-for-bit. O(K^2) comparisons per row — intended for the
-    kernel's small W, not for the batched (V, K) build.
+    ``_alias_build_row_flat``: both take their cumulative lines from
+    ``prefix_sum``, a binary search on a nondecreasing line returns
+    exactly its comparison count, and one-hot reductions (one selected
+    value and exact zeros) reproduce gathers bit for bit. O(K^2)
+    comparisons per row — meant for the kernel's small W, not for the
+    batched (V, K) build.
     """
-    k = p.shape[0]
-    q = _normalized(p)
-    # iota-free positions: TPU Pallas rejects 1-D iota; cumsum lowers.
-    ones = jnp.ones((k,), jnp.int32)
-    pos = jnp.cumsum(ones) - 1
+    shape = p.shape
+    k = shape[-1]
+    p = p.reshape(1, k)
+    q = _normalized(p, roll)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
     small = q < 1.0
     large = ~small
-    ns = jnp.sum(small.astype(jnp.int32))
+    smi = small.astype(jnp.int32)
+    lgi = 1 - smi
+    ns = jnp.sum(smi)
     nl = k - ns
 
     d = jnp.where(small, 1.0 - q, 0.0)
     u = jnp.where(large, q - 1.0, 0.0)
-    dcum = jnp.cumsum(d)
-    ucum = jnp.cumsum(u)
-    rank_s = jnp.cumsum(small.astype(jnp.int32)) - 1
-    rank_l = jnp.cumsum(large.astype(jnp.int32)) - 1
+    dcum = prefix_sum(d, roll)
+    ucum = prefix_sum(u, roll)
+    rank_s = prefix_sum(smi, roll) - 1
+    rank_l = prefix_sum(lgi, roll) - 1
+
+    def row(x):   # row(x)[j, i] = x[i]
+        return jnp.broadcast_to(x, (k, k))
+
+    def col(x):   # col(x)[j, i] = x[j]
+        return jnp.transpose(row(x))
+
+    large_c = col(lgi) > 0
+    small_c = col(smi) > 0
+    rank_l_c = col(rank_l)
+    pos_c = col(pos)
+    dcum_c = col(dcum)
+
+    def count(b):
+        return jnp.sum(b.astype(jnp.int32), axis=0, keepdims=True)
+
+    def pick(b, x):
+        return jnp.sum(jnp.where(b, x, jnp.zeros_like(x)), axis=0,
+                       keepdims=True)
 
     # smalls: r = |{larges j : U[j] < dprev}| == searchsorted(side='left')
     dprev = dcum - d
-    lt = large[None, :] & (ucum[None, :] < dprev[:, None])     # (k, k)
-    r = jnp.sum(lt.astype(jnp.int32), axis=1)
+    r = count(large_c & (col(ucum) < row(dprev)))
     has_donor = small & (r < nl)
-    sel = (large[None, :] & (rank_l[None, :] == r[:, None])).astype(
-        jnp.int32)
-    alias_small = jnp.where(has_donor, jnp.sum(sel * pos[None, :], axis=1),
-                            pos)
+    alias_small = jnp.where(
+        has_donor, pick(large_c & (rank_l_c == row(r)), pos_c), pos)
 
     # larges: mstar = |{smalls m : S[m] <= U[j]}| == side='right'
-    le = small[None, :] & (dcum[None, :] <= ucum[:, None])     # (k, k)
-    mstar = jnp.sum(le.astype(jnp.int32), axis=1)
+    mstar = count(small_c & (dcum_c <= row(ucum)))
     demoted = large & (mstar < ns)
-    sel_m = (small[None, :] & (rank_s[None, :] == mstar[:, None])).astype(
-        jnp.float32)
-    s_at = jnp.sum(sel_m * dcum[None, :], axis=1)
+    s_at = pick(small_c & (col(rank_s) == row(mstar)), dcum_c)
     resid = 1.0 + ucum - s_at
     has_next = demoted & (rank_l + 1 < nl)
-    sel_n = (large[None, :] & (rank_l[None, :] == (rank_l + 1)[:, None])
-             ).astype(jnp.int32)
-    next_l = jnp.sum(sel_n * pos[None, :], axis=1)
+    next_l = pick(large_c & (rank_l_c == row(rank_l + 1)), pos_c)
 
     prob = jnp.where(small, q, jnp.where(demoted, resid, 1.0))
     alias = jnp.where(small, alias_small, jnp.where(has_next, next_l, pos))
     prob = jnp.clip(prob, 0.0, 1.0)
-    return prob.astype(jnp.float32), alias.astype(jnp.int32)
+    return (prob.astype(jnp.float32).reshape(shape),
+            alias.astype(jnp.int32).reshape(shape))
 
 
 def _alias_build_row_psum(p: jax.Array) -> tuple[jax.Array, jax.Array]:

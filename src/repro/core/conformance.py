@@ -13,7 +13,8 @@ with three different execution strategies:
                  dense ascending-topic K-vector (scatter of the table);
   * ``sparse`` — O(W) per token: pure-jnp gathers over the (V, W) table
                  slots (the kernel's jnp oracle);
-  * ``pallas`` — the hdp_z Pallas kernel in interpret mode.
+  * ``pallas`` — the hdp_z Pallas kernel: compiled on a TPU, in
+                 interpret mode elsewhere (``resolve_interpret``).
 
 Bitwise agreement relies on tables built with ``order="topic"``: slots
 sorted by ascending topic id, so every left-to-right partial sum over
@@ -134,6 +135,5 @@ def z_step_conformant(
     if impl == "pallas":
         return hdp_z_pallas(
             tokens, mask, z, uniforms, q_a, fpack, ipack, kk=kk,
-            interpret=True,
         )
     raise ValueError(f"unknown conformance impl {impl!r}")

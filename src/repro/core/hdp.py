@@ -71,8 +71,6 @@ class HDPConfig(NamedTuple):
     exact_phi: bool = False  # Algorithm 1: exact Dirichlet Phi instead of PPU
     hist_cap: int = 256      # P: per-(doc,topic) count cap for the l histogram
     unroll_z: bool = False   # unroll the in-document sweep (cost probes)
-    pallas_interpret: bool | None = None  # None: $REPRO_PALLAS_INTERPRET /
-    #                          backend default (kernels/hdp_z/ops.py)
     alias_in_kernel: str = "auto"  # pallas only: build term-(a) alias
     #                          tables inside the z kernel (auto|on|off;
     #                          auto = on for compiled TPU, off elsewhere)
@@ -368,27 +366,23 @@ def init_state(
 def _z_step(cfg: HDPConfig, tokens, mask, z, phi, psi, uniforms):
     """Dispatch to the configured z-step.
 
-    Returns ``(z_new, m, dn)`` where dn is the fused (K, V) ``delta_n``
-    when the impl emits it in-sweep (pallas) and None otherwise — the
-    caller falls back to the separate ``delta_n`` scatter.
+    Returns ``(z_new, m)``: the new assignments and their (D, K)
+    per-document histogram from the sweep carry.
     """
     if cfg.z_impl == "dense":
-        z_new, m = z_step_dense(tokens, mask, z, phi, psi, cfg.alpha,
-                                uniforms, unroll=cfg.unroll_z)
-        return z_new, m, None
+        return z_step_dense(tokens, mask, z, phi, psi, cfg.alpha,
+                            uniforms, unroll=cfg.unroll_z)
     if cfg.z_impl == "sparse":
         q_a, aprob, aalias = build_alias_tables(phi, psi, cfg.alpha)
-        z_new, m = z_step_sparse_tables(
+        return z_step_sparse_tables(
             tokens, mask, z, phi, cfg.alpha, uniforms, cfg.bucket,
             q_a, aprob, aalias, unroll=cfg.unroll_z,
         )
-        return z_new, m, None
     if cfg.z_impl == "pallas":
         from repro.kernels.hdp_z import ops as zops
 
         return zops.z_step_pallas(
             tokens, mask, z, phi, psi, cfg.alpha, uniforms, cfg.bucket,
-            interpret=cfg.pallas_interpret, emit_delta=True,
             alias_in_kernel=cfg.alias_in_kernel,
         )
     raise ValueError(f"unknown z_impl {cfg.z_impl!r}")
@@ -413,11 +407,8 @@ def gibbs_iteration(
     #    histogram m, and n advances by the exact integer delta over
     #    changed tokens — no from-zero recount (see module docstring).
     uniforms = jax.random.uniform(k_u, tokens.shape + (3,), jnp.float32)
-    z, m, dn = _z_step(cfg, tokens, mask, state.z, phi, state.psi, uniforms)
-
-    if dn is None:
-        dn = delta_n(state.z, z, tokens, mask, cfg.K, cfg.V)
-    n = state.n + dn
+    z, m = _z_step(cfg, tokens, mask, state.z, phi, state.psi, uniforms)
+    n = state.n + delta_n(state.z, z, tokens, mask, cfg.K, cfg.V)
     dh = d_histogram(m, cfg.hist_cap)
 
     # 3. l-step (binomial trick; parallel over topics, constant in D/N)

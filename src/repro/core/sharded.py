@@ -99,7 +99,7 @@ class ShardedHDP:
 
             self.alias_in_kernel = zops.resolve_alias_in_kernel(
                 cfg.alias_in_kernel,
-                interpret=zops.resolve_interpret(cfg.pallas_interpret),
+                interpret=zops.resolve_interpret(),
                 compact=compact_tables,
             )
 
@@ -260,10 +260,8 @@ class ShardedHDP:
 
     def _z_sweep(self, ztables, z, tokens, mask, psi, k_u):
         """Step 4: z-step on the local document shard (no communication).
-        Returns ``(z_new, m, dn)`` — every impl emits its per-doc
-        histogram; the pallas kernel additionally emits the fused (K, V)
-        ``delta_n`` (dn is None for dense/sparse, and ``_block_stats``
-        falls back to the separate scatter).
+        Returns ``(z_new, m)`` — every impl emits its per-doc
+        histogram from the sweep carry.
 
         ``k_u`` must already be block-specific for streaming; the
         per-device fold happens here so a single-block stream consumes
@@ -289,22 +287,19 @@ class ShardedHDP:
             q_a, fpack, ipack = ztables
             return zops.hdp_z_pallas(
                 tokens, mask, z, u, q_a, fpack, ipack, kk=cfg.K,
-                interpret=zops.resolve_interpret(cfg.pallas_interpret),
-                emit_delta=True, in_kernel=self.alias_in_kernel,
+                in_kernel=self.alias_in_kernel,
             )
         if cfg.z_impl == "dense":
             (phi,) = ztables
-            z_new, m = H.z_step_dense(tokens, mask, z, phi, psi, cfg.alpha,
-                                      u, unroll=cfg.unroll_z)
-            return z_new, m, None
+            return H.z_step_dense(tokens, mask, z, phi, psi, cfg.alpha,
+                                  u, unroll=cfg.unroll_z)
         phi, q_a, aprob, aalias = ztables
-        z_new, m = H.z_step_sparse_tables(
+        return H.z_step_sparse_tables(
             tokens, mask, z, phi, cfg.alpha, u, cfg.bucket,
             q_a, aprob, aalias, unroll=cfg.unroll_z,
         )
-        return z_new, m, None
 
-    def _block_stats(self, z_old, z_new, m, tokens, mask, dn=None):
+    def _block_stats(self, z_old, z_new, m, tokens, mask):
         """Steps 5-7a: sufficient-statistic *deltas* for one block.
 
         Returns (dn_shard, dh) — the vocab-sharded exact integer update
@@ -313,13 +308,11 @@ class ShardedHDP:
         from the sweep-emitted m. Both are pure sums over documents, so
         per-block results merge by addition (exactly: integer
         arithmetic throughout). No count_n / doc_topic_counts recompute
-        happens here — the sweep already holds both, and when the sweep
-        fused the delta scatter too (``dn`` not None) even the separate
-        ``delta_n`` pass disappears.
+        happens here — the sweep already holds m, and ``delta_n``
+        scatters over changed tokens only.
         """
         cfg = self.cfg
-        dn_local = (H.delta_n(z_old, z_new, tokens, mask, cfg.K, cfg.V)
-                    if dn is None else dn)
+        dn_local = H.delta_n(z_old, z_new, tokens, mask, cfg.K, cfg.V)
         dn_shard = jax.lax.psum_scatter(
             dn_local, self.model_axis, scatter_dimension=1, tiled=True
         )
@@ -336,8 +329,8 @@ class ShardedHDP:
         phi_shard, varphi_shard, ztables = self._phi_tables(
             n_shard, psi, k_phi
         )
-        z_new, m, dn = self._z_sweep(ztables, z, tokens, mask, psi, k_u)
-        dn_shard, dh = self._block_stats(z, z_new, m, tokens, mask, dn=dn)
+        z_new, m = self._z_sweep(ztables, z, tokens, mask, psi, k_u)
+        dn_shard, dh = self._block_stats(z, z_new, m, tokens, mask)
         z = z_new
         n_shard = n_shard + dn_shard
 
@@ -447,8 +440,8 @@ class ShardedHDP:
         s = self.specs()
 
         def local(ztables, z, tokens, mask, psi, k_ub):
-            z_new, m, dn = self._z_sweep(ztables, z, tokens, mask, psi, k_ub)
-            dn_shard, dh = self._block_stats(z, z_new, m, tokens, mask, dn=dn)
+            z_new, m = self._z_sweep(ztables, z, tokens, mask, psi, k_ub)
+            dn_shard, dh = self._block_stats(z, z_new, m, tokens, mask)
             return z_new, dn_shard, dh
 
         return compat.shard_map(
@@ -498,10 +491,8 @@ class ShardedHDP:
                 (block_docs, tokens.shape[1], 3), jnp.float32,
             )
             u = jax.lax.slice_in_dim(u_full, lo, lo + rows, axis=0)
-            z_new, m, dn = self._z_sweep_u(ztables, z, tokens, mask,
-                                           psi, u)
-            if dn is None:
-                dn = H.delta_n(z, z_new, tokens, mask, cfg.K, cfg.V)
+            z_new, m = self._z_sweep_u(ztables, z, tokens, mask, psi, u)
+            dn = H.delta_n(z, z_new, tokens, mask, cfg.K, cfg.V)
             dh = H.d_histogram(m, cfg.hist_cap)
             return z_new, dn, dh
 

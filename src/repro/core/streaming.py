@@ -221,7 +221,7 @@ class StreamingHDP:
     ``n_devices`` (default: the ``REPRO_STREAM_DEVICES`` env var, else
     1) turns on the data-parallel lane mode: each block's document rows
     split evenly across the first ``n_devices`` jax devices, every lane
-    runs the fused z-sweep on its row shard concurrently (its own
+    runs the z-sweep on its row shard concurrently (its own
     ``_SweepLane`` thread + device), and the per-lane integer deltas
     merge through the sparse bit-packed ``data/deltawire.py`` exchange
     — ``n_run += reduce(pack(delta_d))``, bitwise-equal to the

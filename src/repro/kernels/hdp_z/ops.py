@@ -18,28 +18,11 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.core.alias import alias_build
-from repro.kernels.hdp_z.hdp_z import hdp_z_pallas
+from repro.core.alias import alias_build, prefix_sum
+from repro.kernels.hdp_z.hdp_z import hdp_z_pallas, resolve_interpret
 from repro.kernels.hdp_z.ref import hdp_z_ref, hdp_z_ref_prologue
 
 _FALSY = ("0", "false", "no", "off", "")
-
-
-def resolve_interpret(explicit: bool | None = None) -> bool:
-    """Resolve the Pallas execution mode for this process.
-
-    Precedence: an explicit boolean (config field / kwarg) wins; else the
-    ``REPRO_PALLAS_INTERPRET`` env var; else interpret mode exactly when
-    the backend is not a TPU (the kernel only compiles on TPU — interpret
-    mode is the CPU/GPU conformance path). Called at trace time: the
-    result is a static argument of the jitted kernel wrapper.
-    """
-    if explicit is not None:
-        return bool(explicit)
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env.strip().lower() not in _FALSY
-    return jax.default_backend() != "tpu"
 
 
 def resolve_alias_in_kernel(
@@ -144,7 +127,7 @@ def build_word_sparse_tables(
         )
     vals, ids = _word_supports(phi.T, w, order)
     wa = vals * (jnp.float32(alpha) * psi)[ids]
-    q_a = jnp.sum(wa, axis=-1)
+    q_a = prefix_sum(wa)[..., -1]
     aprob, aalias = alias_build(wa)
     if compact:
         fpack = jnp.stack(
@@ -192,7 +175,7 @@ def build_word_sparse_tables_masked(
     (rows,) = jnp.nonzero(u_mask, size=cap, fill_value=0)
     vals, ids = _word_supports(phi.T[rows], w, order)
     wa = vals * (jnp.float32(alpha) * psi)[ids]
-    q_a_sub = jnp.sum(wa, axis=-1)
+    q_a_sub = prefix_sum(wa)[..., -1]
     aprob, aalias = alias_build(wa)
     if compact:
         fpack_sub = jnp.stack(
@@ -273,22 +256,21 @@ def _z_step_pallas_fused(
 
 def z_step_pallas(
     tokens, mask, z, phi, psi, alpha, uniforms, bucket, *,
-    order="value", compact=False, interpret=None, emit_delta=False,
-    alias_in_kernel="auto",
+    order="value", compact=False, emit_delta=False, alias_in_kernel="auto",
 ):
     """Drop-in z-step: builds tables then runs the kernel (W = bucket),
     fused into a single jitted dispatch (no host hop between the table
     epilogue and the sweep).
 
     ``order``/``compact`` select the table variant (see
-    ``build_word_sparse_tables``); ``interpret=None`` resolves via
-    ``resolve_interpret`` (env var / backend default);
+    ``build_word_sparse_tables``); the kernel runs compiled on a TPU and
+    in interpret mode elsewhere (``resolve_interpret``);
     ``alias_in_kernel`` ("auto"/"on"/"off", see
     ``resolve_alias_in_kernel``) selects the kernel-prologue alias
     build over the epilogue-fused tables. Returns ``(z_new, m)`` like
-    every z-step (core/hdp.py docstring), plus the fused (K, V)
-    ``delta_n`` when ``emit_delta=True``."""
-    interp = resolve_interpret(interpret)
+    every z-step (core/hdp.py docstring), plus the (K, V) ``delta_n``
+    when ``emit_delta=True``."""
+    interp = resolve_interpret()
     return _z_step_pallas_fused(
         tokens, mask, z, phi, psi, alpha, uniforms,
         bucket=bucket, order=order, compact=compact,

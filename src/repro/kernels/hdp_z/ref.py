@@ -3,7 +3,9 @@
 Identical math over the identical word-sparse tables consuming the
 identical uniforms — tests assert *bitwise* equality of the sampled z
 (and of the emitted per-doc histogram m) against the kernel in
-interpret mode. Like every z-step, returns ``(z_new, m)`` with m the
+interpret mode. Row totals and the term-(b) cumulative line come from
+``core.alias.prefix_sum``, the same order of additions the compiled
+kernel uses, so the oracle is exact on the chip as well. Like every z-step, returns ``(z_new, m)`` with m the
 (D, K) sweep-carry histogram of z_new.
 """
 
@@ -12,7 +14,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.alias import alias_build_row_onehot
+from repro.core.alias import alias_build_row_onehot, prefix_sum
 
 
 def hdp_z_ref(
@@ -48,14 +50,14 @@ def hdp_z_ref(
 
             mb = m[ids].astype(jnp.float32)
             wb = vals * mb
-            qb = jnp.sum(wb)
+            c = prefix_sum(wb)
+            qb = c[-1]
             qa = q_a[v]
             tot = qa + qb
 
             u1, u2, u3 = u_d[i, 0], u_d[i, 1], u_d[i, 2]
             t = u1 * tot
 
-            c = jnp.cumsum(wb)
             slot_b = jnp.minimum(jnp.sum((c < t).astype(jnp.int32)), w - 1)
             k_doc = ids[slot_b]
 
@@ -127,18 +129,18 @@ def hdp_z_ref_prologue(
             vals = vals_all[v].astype(jnp.float32)
             ids = ids_all[v].astype(jnp.int32)
             wa = vals * apsi[ids]
-            qa = jnp.sum(wa)
+            qa = prefix_sum(wa)[-1]
             aprob, aalias = alias_build_row_onehot(wa)
 
             mb = m[ids].astype(jnp.float32)
             wb = vals * mb
-            qb = jnp.sum(wb)
+            c = prefix_sum(wb)
+            qb = c[-1]
             tot = qa + qb
 
             u1, u2, u3 = u_d[i, 0], u_d[i, 1], u_d[i, 2]
             t = u1 * tot
 
-            c = jnp.cumsum(wb)
             slot_b = jnp.minimum(jnp.sum((c < t).astype(jnp.int32)), w - 1)
             k_doc = ids[slot_b]
 

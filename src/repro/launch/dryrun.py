@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (architecture x input-shape x
 mesh) cell on 512 placeholder host devices, and extract the roofline
 inputs (HLO FLOPs, bytes, per-collective traffic, memory analysis).
@@ -9,10 +6,19 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen1.5-32b --shape train_4k
   PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both --out dryrun.json
 
-The 512-device XLA flag is set at the very top of this module, before
-any jax import, and ONLY here — tests and benches see the real device
-count.
+The 512-device XLA flag is added to ``XLA_FLAGS`` only when this module
+runs as a script, before jax is imported here: a module that imports it
+(tests, benches, the LM trainer) keeps the real device count and any
+flags set from outside.
 """
+
+import os
+
+if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+        os.environ.get("XLA_FLAGS", ""),
+        "--xla_force_host_platform_device_count=512",
+    ]))
 
 import argparse
 import dataclasses

@@ -253,6 +253,9 @@ def main():
                          "completions into per-bucket ok/miss counters "
                          "(fleet mode)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke and not args.train_iters:
         args.train_iters = 20
     if not args.snapshot and not args.registry and not args.train_iters:

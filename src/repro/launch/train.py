@@ -99,27 +99,59 @@ def _stream_devices(args):
     return int(os.environ.get("REPRO_STREAM_DEVICES", "1") or "1")
 
 
+def build_hdp_sampler(corpus, *, topics: int, bucket=None,
+                      z_impl: str = "sparse"):
+    """``(corpus, ShardedHDP)`` on the explicit single-device mesh.
+
+    The model, the key schedule and the non-sweep ops live on
+    ``jax.devices()[0]`` however many devices the host has: a mesh over
+    every visible device would fold per-shard keys into the chain and
+    sample a mesh-shaped chain instead of the canonical one. Data
+    parallelism is the streaming lane mode (``build_streaming``), which
+    keeps the chain bitwise-identical at every lane count.
+    """
+    from repro import compat
+    from repro.core import hdp as H
+    from repro.core.sharded import ShardedHDP
+    from repro.data.corpus import shard_balanced
+
+    mesh = compat.single_device_mesh()
+    corpus = shard_balanced(corpus, 1)
+    # auto bucket: the sparse z-step needs bucket >= min(K, L) (enforced
+    # at sampler construction since the delta-stats PR).
+    if bucket is None:
+        bucket = min(topics, corpus.max_len)
+    cfg = H.HDPConfig(K=topics, V=corpus.V, bucket=bucket, z_impl=z_impl,
+                      hist_cap=min(corpus.max_len, 256))
+    return corpus, ShardedHDP(mesh, cfg)
+
+
+def build_streaming(corpus, sh, *, block_docs: int, devices: int = 1,
+                    z_store=None, z_dir=None, z_pack=None):
+    """The minibatch driver over ``corpus`` for sampler ``sh``, with
+    ``devices`` data-parallel sweep lanes (blocks pad to a doc count the
+    lanes divide evenly)."""
+    from repro.core.streaming import StreamingHDP
+    from repro.data.stream import ShardedCorpusStore
+
+    store = ShardedCorpusStore.from_corpus(corpus, block_docs,
+                                           doc_multiple=devices)
+    return StreamingHDP(sh, store, z_store=z_store, z_dir=z_dir,
+                        z_pack=z_pack, n_devices=devices)
+
+
 def train_hdp_streaming(args, corpus, sh):
     """Minibatch path: corpus swept block-by-block in bounded device
     memory, resumable mid-epoch (block cursor + RNG in the checkpoint).
     With --z-store disk, z slabs are out-of-core too (bounded host
     memory): they live as per-block version files rooted at --z-dir
     (default: the checkpoint dir, which makes saves near-free)."""
-    from repro.core.streaming import StreamingHDP
-    from repro.data.stream import ShardedCorpusStore
-
-    data_size = (int(sh.mesh.devices.size)
-                 // dict(sh.mesh.shape)[sh.model_axis])
-    devices = _stream_devices(args)
-    store = ShardedCorpusStore.from_corpus(
-        # blocks must pad to a doc count both the mesh's data axis and
-        # the lane split can divide evenly
-        corpus, args.block_docs,
-        doc_multiple=int(np.lcm(data_size, devices))
-    )
-    stream = StreamingHDP(sh, store, z_store=args.z_store,
-                          z_dir=args.z_dir or args.ckpt,
-                          z_pack=args.z_pack, n_devices=devices)
+    stream = build_streaming(corpus, sh, block_docs=args.block_docs,
+                             devices=_stream_devices(args),
+                             z_store=args.z_store,
+                             z_dir=args.z_dir or args.ckpt,
+                             z_pack=args.z_pack)
+    store = stream.store
     state, resume_kw = (None, {})
     if args.ckpt:
         state, resume_kw = stream.restore(args.ckpt)
@@ -163,35 +195,14 @@ def train_hdp_streaming(args, corpus, sh):
 
 def train_hdp(args):
     from repro.core import hdp as H
-    from repro.core.sharded import ShardedHDP
-    from repro.data.corpus import shard_balanced
     from repro.data.synthetic import paper_corpus
     from repro.train import checkpoint as CKPT
 
     rng = np.random.default_rng(args.seed)
     corpus = paper_corpus(args.hdp, rng, scale=args.scale, max_len=args.max_len)
-    # lane mode (streaming, --devices > 1) keeps the model and key
-    # schedule on ONE device — the lane threads place the sweeps across
-    # devices themselves — so the chain stays bitwise-identical to the
-    # canonical single-device run. A multi-device primary mesh would
-    # fold per-shard keys into the non-sweep ops and sample a
-    # mesh-shaped chain instead (StreamingHDP rejects it).
-    lane_mode = args.stream and _stream_devices(args) > 1
-    from repro import compat
-    mesh = (compat.single_device_mesh() if lane_mode
-            else MESH.make_host_mesh())
-    n_dev = 1 if lane_mode else len(jax.devices())
-    corpus = shard_balanced(corpus, n_dev)
-    k_topics = args.topics
-    v_pad = ((corpus.V + mesh.shape["model"] - 1) // mesh.shape["model"]
-             ) * mesh.shape["model"]
-    # auto bucket: the sparse z-step needs bucket >= min(K, L) (enforced
-    # at sampler construction since the delta-stats PR).
-    bucket = (min(k_topics, corpus.max_len) if args.bucket is None
-              else args.bucket)
-    cfg = H.HDPConfig(K=k_topics, V=v_pad, bucket=bucket,
-                      z_impl=args.z_impl, hist_cap=min(corpus.max_len, 256))
-    sh = ShardedHDP(mesh, cfg)
+    corpus, sh = build_hdp_sampler(corpus, topics=args.topics,
+                                   bucket=args.bucket, z_impl=args.z_impl)
+    cfg = sh.cfg
     if args.stream:
         return train_hdp_streaming(args, corpus, sh)
     tokens = jax.device_put(jnp.asarray(corpus.tokens), sh.corpus_shardings()[0])
@@ -297,6 +308,9 @@ def main():
                          "(default: iteration boundaries only)")
     args = ap.parse_args()
     from repro import obs
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     obs.setup(trace=args.trace, metrics_path=args.metrics,
               metrics_every_s=args.metrics_every)
     try:

@@ -72,6 +72,14 @@ def _engine_step(snap, tokens, mask, z, seeds, sweeps, base_key, *,
     )
 
 
+def _upload(host: np.ndarray) -> jax.Array:
+    """Device copy of a host staging array that the engine mutates in
+    place. The upload reads a private snapshot: a transfer that aliased
+    (the CPU backend may zero-copy) or read the live array after the
+    call returned would see the next admission's or step's writes."""
+    return jnp.asarray(host.copy())
+
+
 @dataclass
 class _Slots:
     """One length bucket's slot pool. tokens/mask/seeds are host staging
@@ -110,9 +118,9 @@ class _Slots:
 
     def device_batch(self):
         if self.d_tokens is None:
-            self.d_tokens = jnp.asarray(self.tokens)
-            self.d_mask = jnp.asarray(self.mask)
-            self.d_seeds = jnp.asarray(self.seeds)
+            self.d_tokens = _upload(self.tokens)
+            self.d_mask = _upload(self.mask)
+            self.d_seeds = _upload(self.seeds)
         return self.d_tokens, self.d_mask, self.d_seeds
 
 
@@ -366,7 +374,7 @@ class ServeEngine:
                 d_tokens, d_mask, d_seeds = pool.device_batch()
                 pool.z, pool.m = self._step_fn(
                     self.snap, d_tokens, d_mask, pool.z, d_seeds,
-                    jnp.asarray(pool.sweeps), self.base_key, impl=self.impl,
+                    _upload(pool.sweeps), self.base_key, impl=self.impl,
                     has_fresh=has_fresh,
                 )
             live = np.array([r is not None for r in pool.req])

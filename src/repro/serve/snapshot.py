@@ -87,7 +87,8 @@ def build_snapshot(
     """Distill (Phi, Psi) into a snapshot.
 
     ``w`` defaults to the exact table width: the largest per-word topic
-    support in Phi, rounded up to a lane-friendly multiple of 8. Passing
+    support in Phi, rounded up to a multiple of 128 (one TPU lane row,
+    which the compiled hdp_z kernel needs) and capped at K. Passing
     a smaller ``w`` drops each word's smallest-phi topics beyond W —
     a lossy, smaller artifact; the default is exact.
     """
@@ -95,7 +96,7 @@ def build_snapshot(
     psi = jnp.asarray(psi, jnp.float32)
     k = phi.shape[0]
     if w is None:
-        w = max(_round_up(int(zops.max_column_nnz(phi)), 8), 8)
+        w = max(_round_up(int(zops.max_column_nnz(phi)), 128), 128)
     w = min(w, k)
     if compact:
         validate_compact(k, "build_snapshot(phi)")
