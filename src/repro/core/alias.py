@@ -60,10 +60,11 @@ def prefix_sum(x: jax.Array, roll=jnp.roll) -> jax.Array:
     additions: log2(n) doubling steps, ``x += shift(x, s)`` for
     s = 1, 2, 4, ... with zeros shifted in (Hillis and Steele 1986).
 
-    Every row total and cumulative line of the z-step map goes through
-    this helper — in XLA (``roll=jnp.roll``) and inside the hdp_z kernel
-    (``roll=pltpu.roll``) — so the kernel, its oracle and the table
-    builders add the same floats in the same order on every backend.
+    Every row total and cumulative line of the hdp_z z-step map goes
+    through this helper — in XLA (``roll=jnp.roll``) and inside the
+    kernel (``roll=pltpu.roll``) — so the kernel, its oracle and the
+    word-sparse table builders add the same floats in the same order on
+    every backend.
     ``jnp.cumsum`` gives no such promise: XLA rewrites it into blocked
     scans whose shape depends on the length and the platform. Lane l
     depends only on lanes <= l, so padding past the end changes no
@@ -87,7 +88,7 @@ def last_lane(x: jax.Array) -> jax.Array:
                    axis=-1, keepdims=True)
 
 
-def _normalized(p: jax.Array, roll=jnp.roll) -> jax.Array:
+def _normalized(p: jax.Array, cumsum=prefix_sum) -> jax.Array:
     """q = p / mean(p): the alias construction's working scale, where
     "small" entries sit below 1.
 
@@ -97,17 +98,19 @@ def _normalized(p: jax.Array, roll=jnp.roll) -> jax.Array:
     sampled garbage without tripping any error), and all-zero rows
     (e.g. padded vocab entries, or rows that were entirely non-finite)
     fall back to uniform. Kernel-safe: comparisons and selects only; the
-    total is the last lane of ``prefix_sum``.
+    total is the last lane of ``cumsum``.
     """
     p = jnp.where((p > 0) & (p < jnp.inf), p, 0.0)
-    total = last_lane(prefix_sum(p, roll))
+    total = last_lane(cumsum(p))
     return jnp.where(
         total > 0, p / jnp.maximum(total, 1e-30) * p.shape[-1],
         jnp.ones_like(p),
     )
 
 
-def _alias_build_row_flat(p: jax.Array) -> tuple[jax.Array, jax.Array]:
+def _alias_build_row_flat(
+    p: jax.Array, cumsum=prefix_sum
+) -> tuple[jax.Array, jax.Array]:
     """Build one alias table from an unnormalized weight vector ``p`` (K,)
     via the sort-free, index-ordered prefix-sum partition.
 
@@ -136,7 +139,7 @@ def _alias_build_row_flat(p: jax.Array) -> tuple[jax.Array, jax.Array]:
     ``argsort`` removes the most expensive op of the batched build.
     """
     k = p.shape[0]
-    q = _normalized(p)
+    q = _normalized(p, cumsum)
     pos = jnp.arange(k, dtype=jnp.int32)
     small = q < 1.0
     large = ~small
@@ -148,8 +151,8 @@ def _alias_build_row_flat(p: jax.Array) -> tuple[jax.Array, jax.Array]:
 
     d = jnp.where(small, 1.0 - q, 0.0)
     u = jnp.where(large, q - 1.0, 0.0)
-    dcum = prefix_sum(d)        # S: plateaus at larges
-    ucum = prefix_sum(u)        # U: plateaus at smalls
+    dcum = cumsum(d)            # S: plateaus at larges
+    ucum = cumsum(u)            # U: plateaus at smalls
 
     # Both monotone lines are searched at *full length*; the count of
     # larges (resp. smalls) inside the located prefix converts a
@@ -209,7 +212,7 @@ def alias_build_row_onehot(
     shape = p.shape
     k = shape[-1]
     p = p.reshape(1, k)
-    q = _normalized(p, roll)
+    q = _normalized(p, functools.partial(prefix_sum, roll=roll))
     pos = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)
     small = q < 1.0
     large = ~small
@@ -427,15 +430,24 @@ def _alias_build_row_scan(p: jax.Array) -> tuple[jax.Array, jax.Array]:
     return prob.astype(jnp.float32), alias.astype(jnp.int32)
 
 
-@functools.partial(jax.jit)
-def alias_build(p: jax.Array) -> tuple[jax.Array, jax.Array]:
+@functools.partial(jax.jit, static_argnames=("cumsum",))
+def alias_build(
+    p: jax.Array, cumsum=prefix_sum
+) -> tuple[jax.Array, jax.Array]:
     """Vectorized alias build (sort-free index-ordered partition).
 
     p: (..., K) unnormalized weights — one table per leading index.
     Returns (prob, alias) with the same leading shape.
+
+    ``cumsum`` makes the row total and the cumulative lines. The default
+    ``prefix_sum`` adds in the order the hdp_z kernel does, so the
+    word-sparse tables equal its in-VMEM build bitwise; the dense (V, K)
+    tables, which nothing compiled has to match, pass ``jnp.cumsum``
+    (one pass per line instead of log2(K)).
     """
     flat = p.reshape((-1, p.shape[-1]))
-    prob, alias = jax.vmap(_alias_build_row_flat)(flat)
+    prob, alias = jax.vmap(
+        functools.partial(_alias_build_row_flat, cumsum=cumsum))(flat)
     return prob.reshape(p.shape), alias.reshape(p.shape)
 
 

@@ -226,7 +226,7 @@ def build_alias_tables(
     """
     wa = (phi * (alpha * psi)[:, None]).T  # (V, K)
     q_a = jnp.sum(wa, axis=1)  # (V,)
-    prob, alias = alias_build(wa)
+    prob, alias = alias_build(wa, cumsum=jnp.cumsum)
     return q_a, prob, alias
 
 

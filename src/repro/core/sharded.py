@@ -239,13 +239,13 @@ class ShardedHDP:
                     u_mask_shard, size=min(mask_cap, wa.shape[0]),
                     fill_value=0,
                 )
-                p_sub, a_sub = alias_build(wa[rows])
+                p_sub, a_sub = alias_build(wa[rows], cumsum=jnp.cumsum)
                 prob_shard = jnp.zeros(wa.shape, jnp.float32).at[rows].set(
                     p_sub)
                 alias_shard = jnp.zeros(wa.shape, jnp.int32).at[rows].set(
                     a_sub)
             else:
-                prob_shard, alias_shard = alias_build(wa)
+                prob_shard, alias_shard = alias_build(wa, cumsum=jnp.cumsum)
             qa_shard = jnp.sum(wa, axis=1)
             q_a = jax.lax.all_gather(qa_shard, maxis, axis=0, tiled=True)
             aprob = jax.lax.all_gather(prob_shard, maxis, axis=0, tiled=True)
@@ -255,7 +255,7 @@ class ShardedHDP:
             # gathered Phi — trades (V,K) fp32+int32 gather for local compute.
             wa = (phi * (cfg.alpha * psi)[:, None]).T
             q_a = jnp.sum(wa, axis=1)
-            aprob, aalias = alias_build(wa)
+            aprob, aalias = alias_build(wa, cumsum=jnp.cumsum)
         return phi_shard, varphi_shard, (phi, q_a, aprob, aalias)
 
     def _z_sweep(self, ztables, z, tokens, mask, psi, k_u):

@@ -46,12 +46,11 @@ Outputs follow the repo-wide z-step contract: ``(z_new, m)`` where m is
 the (D, K) per-document topic histogram of z_new, written from the
 kernel's VMEM-resident sweep carry after each document's sweep.
 
-``emit_delta=True`` also returns ``dn``, the (K, V) exact integer update
-to the topic-word statistic over *changed* live tokens, computed by the
-XLA scatter ``core.hdp.delta_n`` after the kernel. The kernel never
-accumulates it itself: a (K, V) int32 block resident in VMEM is 28 MB at
-the AP cell (K=1000, V=7168) and 360 MB at PubMed width, beyond what
-the chip's VMEM holds, so every width takes the same unfused path.
+The kernel does not accumulate the (K, V) update to the topic-word
+statistic: a (K, V) int32 block resident in VMEM is 28 MB at the AP cell
+(K=1000, V=7168) and 360 MB at PubMed width, beyond what the chip's VMEM
+holds. Callers take it from the XLA scatter ``core.hdp.delta_n`` after
+the sweep, the same way at every width.
 
 With ``in_kernel=True`` (the kernel-prologue alias build, gated by
 ``HDPConfig.alias_in_kernel``) the packed-table inputs are replaced by
@@ -242,8 +241,7 @@ def _z_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("kk", "doc_block", "interpret", "emit_delta",
-                     "in_kernel"),
+    static_argnames=("kk", "doc_block", "interpret", "in_kernel"),
 )
 def hdp_z_pallas(
     tokens: jax.Array,   # (D, L) int32
@@ -257,9 +255,8 @@ def hdp_z_pallas(
     kk: int,
     doc_block: int = 8,
     interpret: bool | None = None,
-    emit_delta: bool = False,
     in_kernel: bool = False,
-) -> tuple[jax.Array, ...]:
+) -> tuple[jax.Array, jax.Array]:
     interpret = resolve_interpret(interpret)
     d, l = tokens.shape
     v, w = fpack.shape[0], fpack.shape[-1]
@@ -331,10 +328,4 @@ def hdp_z_pallas(
         interpret=interpret,
         name="hdp_z",
     )(tok_p, msk_p, z_p, u_p, side, f_in, i_in)
-    z_new = z_out[:d]
-    m = m_out.reshape(d_pad, r * LANES)[:d, :kk]
-    if not emit_delta:
-        return z_new, m
-    from repro.core.hdp import delta_n
-
-    return z_new, m, delta_n(z, z_new, tokens, mask, kk, v)
+    return z_out[:d], m_out.reshape(d_pad, r * LANES)[:d, :kk]

@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.alias import alias_build, prefix_sum
+from repro.core.hdp import delta_n
 from repro.kernels.hdp_z.hdp_z import hdp_z_pallas, resolve_interpret
 from repro.kernels.hdp_z.ref import hdp_z_ref, hdp_z_ref_prologue
 
@@ -236,22 +237,29 @@ def _z_step_pallas_fused(
     With ``in_kernel=True`` the alias epilogue disappears entirely: only
     the raw supports (vals, ids) are materialized, and the kernel builds
     wa / q_a / the alias row per token in VMEM (the kernel-prologue
-    path)."""
+    path).
+
+    ``emit_delta=True`` appends the (K, V) ``delta_n`` XLA scatter; the
+    kernel itself only returns ``(z_new, m)``."""
     if in_kernel:
         vals, ids = build_word_sparse_supports(phi, bucket, order=order)
         apsi = jnp.float32(alpha) * psi
-        return hdp_z_pallas(
+        out = hdp_z_pallas(
             tokens, mask, z, uniforms, apsi, vals, ids,
-            kk=phi.shape[0], interpret=interpret, emit_delta=emit_delta,
-            in_kernel=True,
+            kk=phi.shape[0], interpret=interpret, in_kernel=True,
         )
-    q_a, fpack, ipack = build_word_sparse_tables(
-        phi, psi, alpha, bucket, compact=compact, order=order
-    )
-    return hdp_z_pallas(
-        tokens, mask, z, uniforms, q_a, fpack, ipack,
-        kk=phi.shape[0], interpret=interpret, emit_delta=emit_delta,
-    )
+    else:
+        q_a, fpack, ipack = build_word_sparse_tables(
+            phi, psi, alpha, bucket, compact=compact, order=order
+        )
+        out = hdp_z_pallas(
+            tokens, mask, z, uniforms, q_a, fpack, ipack,
+            kk=phi.shape[0], interpret=interpret,
+        )
+    if not emit_delta:
+        return out
+    return out + (delta_n(z, out[0], tokens, mask, phi.shape[0],
+                          phi.shape[1]),)
 
 
 def z_step_pallas(

@@ -105,7 +105,7 @@ def ssm_mixer(p, cfg, x, h0=None, conv_state=None, *, chunk=64):
         y, hf = ssd(
             xh.astype(jnp.float32), dt, a, bm.astype(jnp.float32),
             cm.astype(jnp.float32), h0, chunk=min(chunk, s),
-            use_kernel=True, interpret=True,
+            use_kernel=True, interpret=jax.default_backend() != "tpu",
         )
     else:
         # loop-free chunked SSD: the XLA production path (see ssd/ops.py)

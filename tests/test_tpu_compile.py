@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.hdp import delta_n
 from repro.kernels.hdp_z.hdp_z import hdp_z_pallas
 
 K, W, DOCS = 1000, 128, 64
@@ -75,11 +76,17 @@ def _args(sharding, width: str, in_kernel: bool):
             s((DOCS, length, 3), jnp.float32)] + tables
 
 
-def _compile(sharding, width, in_kernel, emit_delta=False):
-    fn = jax.jit(lambda *a: hdp_z_pallas(
-        *a, kk=K, interpret=False, in_kernel=in_kernel,
-        emit_delta=emit_delta))
-    return fn.lower(*_args(sharding, width, in_kernel)).compile()
+def _compile(sharding, width, in_kernel, with_delta=False):
+    v, _ = WIDTHS[width]
+
+    def step(tokens, mask, z, *rest):
+        z_new, m = hdp_z_pallas(tokens, mask, z, *rest, kk=K,
+                                interpret=False, in_kernel=in_kernel)
+        if not with_delta:
+            return z_new, m
+        return z_new, m, delta_n(z, z_new, tokens, mask, K, v)
+
+    return jax.jit(step).lower(*_args(sharding, width, in_kernel)).compile()
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
@@ -99,7 +106,7 @@ def test_hdp_z_compiles_for_v5e(one_chip, no_compile_cache, width,
 def test_training_delta_path_compiles_for_v5e(one_chip, no_compile_cache):
     """The delta path training takes on the chip: the kernel emits
     (z, m), then ``delta_n`` scatters the (K, V) update in XLA."""
-    compiled = _compile(one_chip, "ap", in_kernel=True, emit_delta=True)
+    compiled = _compile(one_chip, "ap", in_kernel=True, with_delta=True)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "scatter" in text
