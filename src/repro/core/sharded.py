@@ -45,7 +45,6 @@ statistics merge run once per corpus block.
 
 from __future__ import annotations
 
-import functools
 from typing import Sequence
 
 import jax
@@ -174,19 +173,29 @@ class ShardedHDP:
         help: the dense impl (no tables), the kernel-prologue path (no
         epilogue to shrink), and gather_tables=False.
         """
-        cfg = self.cfg
         maxis = self.model_axis
         midx = jax.lax.axis_index(maxis)
 
         # 1. Phi-step: PPU on the local vocab shard (model-parallel).
-        varphi_shard = self._ppu_shard(n_shard, k_phi, midx)
-        row_local = jnp.sum(varphi_shard, axis=1).astype(jnp.float32)
-        row = jax.lax.psum(row_local, maxis)  # (K,)
-        phi_shard = (
-            varphi_shard.astype(jnp.float32) / jnp.maximum(row[:, None], 1.0)
-        ).astype(self.phi_dtype)
+        with jax.named_scope("ppu_draw"):
+            varphi_shard = self._ppu_shard(n_shard, k_phi, midx)
+            row_local = jnp.sum(varphi_shard, axis=1).astype(jnp.float32)
+            row = jax.lax.psum(row_local, maxis)  # (K,)
+            phi_shard = (
+                varphi_shard.astype(jnp.float32)
+                / jnp.maximum(row[:, None], 1.0)
+            ).astype(self.phi_dtype)
 
         # 2./3. Replicate the z-step operands.
+        with jax.named_scope("tables"):
+            ztables = self._z_tables(phi_shard, psi, u_mask_shard, mask_cap)
+        return phi_shard, varphi_shard, ztables
+
+    def _z_tables(self, phi_shard, psi, u_mask_shard, mask_cap):
+        """Steps 2-3: the impl-specific tuple of replicated z-step
+        operands, built from the vocab shard of Phi."""
+        cfg = self.cfg
+        maxis = self.model_axis
         if cfg.z_impl == "pallas":
             from repro.kernels.hdp_z import ops as zops
 
@@ -201,7 +210,7 @@ class ShardedHDP:
                 vals = jax.lax.all_gather(vals_s, maxis, axis=0, tiled=True)
                 ids = jax.lax.all_gather(ids_s, maxis, axis=0, tiled=True)
                 apsi = jnp.float32(cfg.alpha) * psi
-                return phi_shard, varphi_shard, (apsi, vals, ids)
+                return (apsi, vals, ids)
 
             # Word-sparse tables built model-parallel on the vocab shard,
             # then gathered: (V, W) instead of the paper's (K, V) Phi
@@ -220,14 +229,14 @@ class ShardedHDP:
             q_a = jax.lax.all_gather(q_a_s, maxis, axis=0, tiled=True)
             fpack = jax.lax.all_gather(fpack_s, maxis, axis=0, tiled=True)
             ipack = jax.lax.all_gather(ipack_s, maxis, axis=0, tiled=True)
-            return phi_shard, varphi_shard, (q_a, fpack, ipack)
+            return (q_a, fpack, ipack)
 
         # keep the gathered Phi in phi_dtype: converting to f32 here lets
         # XLA hoist the convert BEFORE the all-gather, doubling the wire
         # bytes (verified on HLO). The z-step promotes per-op instead.
         phi = jax.lax.all_gather(phi_shard, maxis, axis=1, tiled=True)
         if cfg.z_impl == "dense":
-            return phi_shard, varphi_shard, (phi,)
+            return (phi,)
         if self.gather_tables:
             wa = (phi_shard.astype(jnp.float32) * (cfg.alpha * psi)[:, None]).T
             if u_mask_shard is not None:
@@ -256,7 +265,7 @@ class ShardedHDP:
             wa = (phi * (cfg.alpha * psi)[:, None]).T
             q_a = jnp.sum(wa, axis=1)
             aprob, aalias = alias_build(wa, cumsum=jnp.cumsum)
-        return phi_shard, varphi_shard, (phi, q_a, aprob, aalias)
+        return (phi, q_a, aprob, aalias)
 
     def _z_sweep(self, ztables, z, tokens, mask, psi, k_u):
         """Step 4: z-step on the local document shard (no communication).
@@ -312,14 +321,16 @@ class ShardedHDP:
         scatters over changed tokens only.
         """
         cfg = self.cfg
-        dn_local = H.delta_n(z_old, z_new, tokens, mask, cfg.K, cfg.V)
-        dn_shard = jax.lax.psum_scatter(
-            dn_local, self.model_axis, scatter_dimension=1, tiled=True
-        )
-        if self.repl_axes:
-            dn_shard = jax.lax.psum(dn_shard, self.repl_axes)
-        dh = H.d_histogram(m, cfg.hist_cap)
-        dh = jax.lax.psum(dh, tuple(self.mesh.axis_names))
+        with jax.named_scope("delta_n"):
+            dn_local = H.delta_n(z_old, z_new, tokens, mask, cfg.K, cfg.V)
+            dn_shard = jax.lax.psum_scatter(
+                dn_local, self.model_axis, scatter_dimension=1, tiled=True
+            )
+            if self.repl_axes:
+                dn_shard = jax.lax.psum(dn_shard, self.repl_axes)
+        with jax.named_scope("d_histogram"):
+            dh = H.d_histogram(m, cfg.hist_cap)
+            dh = jax.lax.psum(dh, tuple(self.mesh.axis_names))
         return dn_shard, dh
 
     # -- the iteration ----------------------------------------------------
@@ -393,8 +404,12 @@ class ShardedHDP:
     def phi_tables_fn(self):
         """(n, psi, k_phi) -> (phi, varphi, ztables); one call/iteration."""
         s = self.specs()
+
+        def phi_tables(n, psi, k_phi):
+            return self._phi_tables(n, psi, k_phi)
+
         return compat.shard_map(
-            self._phi_tables,
+            phi_tables,
             mesh=self.mesh,
             in_specs=(s["n"], s["psi"], s["key"]),
             out_specs=(s["phi"], s["varphi"], self._ztable_specs()),
@@ -422,8 +437,12 @@ class ShardedHDP:
             fn = self.phi_tables_fn()
             return lambda n, psi, k_phi, u_mask: fn(n, psi, k_phi)
         s = self.specs()
+
+        def phi_tables(n, psi, k_phi, u_mask):
+            return self._phi_tables(n, psi, k_phi, u_mask, mask_cap=cap)
+
         return compat.shard_map(
-            functools.partial(self._phi_tables, mask_cap=cap),
+            phi_tables,
             mesh=self.mesh,
             in_specs=(s["n"], s["psi"], s["key"], P(self.model_axis)),
             out_specs=(s["phi"], s["varphi"], self._ztable_specs()),
@@ -439,13 +458,13 @@ class ShardedHDP:
         ``n += dn_contrib`` (core/streaming.py)."""
         s = self.specs()
 
-        def local(ztables, z, tokens, mask, psi, k_ub):
+        def z_block(ztables, z, tokens, mask, psi, k_ub):
             z_new, m = self._z_sweep(ztables, z, tokens, mask, psi, k_ub)
             dn_shard, dh = self._block_stats(z, z_new, m, tokens, mask)
             return z_new, dn_shard, dh
 
         return compat.shard_map(
-            local,
+            z_block,
             mesh=self.mesh,
             in_specs=(
                 self._ztable_specs(), s["z"], s["tokens"], s["mask"],
@@ -485,7 +504,7 @@ class ShardedHDP:
         rows = block_docs // n_lanes
         lo = lane * rows
 
-        def fn(ztables, z, tokens, mask, psi, k_ub):
+        def z_lane(ztables, z, tokens, mask, psi, k_ub):
             u_full = jax.random.uniform(
                 jax.random.fold_in(k_ub, 0),
                 (block_docs, tokens.shape[1], 3), jnp.float32,
@@ -496,7 +515,7 @@ class ShardedHDP:
             dh = H.d_histogram(m, cfg.hist_cap)
             return z_new, dn, dh
 
-        return fn
+        return z_lane
 
     # -- state construction -------------------------------------------------
     def init_state(self, key, tokens, mask) -> H.HDPState:

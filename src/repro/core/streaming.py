@@ -85,7 +85,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.obs.diagnostics import NULL_CLOCK, PhaseClock
 from repro.core import hdp as H
 from repro.core.polya_urn import ppu_sample, ppu_sample_budgeted
 from repro.core.sharded import ShardedHDP
@@ -96,6 +95,21 @@ from repro.data.stream import (AsyncStage, BlockPrefetcher, BlockWriteback,
 from repro.data.zstore import (ZBlockStore, ZSlabStore,  # noqa: F401
                                make_zslab_store, pack_dtype_for)
 from repro.train import checkpoint as CKPT
+
+
+def merge_stats(n, dn, dh, dhc):
+    """A block's statistic merge: ``n += dn``, ``dh += dhc``."""
+    return n + dn, dh + dhc
+
+
+def split_keys(key):
+    """An iteration's keys: (next chain key, k_phi, k_u, k_l, k_psi)."""
+    return jax.random.split(key, 5)
+
+
+def widen_z(z):
+    """A packed z slab widened to the sampler's int32, on device."""
+    return z.astype(jnp.int32)
 
 
 class _SweepLane:
@@ -314,19 +328,20 @@ class StreamingHDP:
         else:
             self._phi_fn = jax.jit(sharded.phi_tables_fn())
         self._z_fn = jax.jit(sharded.z_block_fn(), donate_argnums=(1,))
-        # one jitted dispatch per block for the statistic merge (the
-        # python-level `acc + c` pair it replaces was two uncompiled
-        # dispatches on the driver's critical path).
-        self._merge_fn = jax.jit(
-            lambda n, dn, dh, dhc: (n + dn, dh + dhc))
-        self._split_fn = jax.jit(
-            functools.partial(jax.random.split, num=5))
+        # each program of the iteration is a named function, so its
+        # module reads jit_<name> in a profile: one jitted dispatch per
+        # block for the statistic merge (the python-level `acc + c` pair
+        # it replaces was two uncompiled dispatches on the driver's
+        # critical path), one per iteration for the keys and the tail.
+        self._merge_fn = jax.jit(merge_stats)
+        self._split_fn = jax.jit(split_keys)
         cfg = self.cfg
-        self._tail_fn = jax.jit(
-            lambda dh, psi, k_l, k_psi: (
-                lambda l: (l, sample_psi(k_psi, l, cfg.gamma))
-            )(sample_l(k_l, dh, psi, cfg.alpha))
-        )
+
+        def tail_l_psi(dh, psi, k_l, k_psi):
+            l = sample_l(k_l, dh, psi, cfg.alpha)
+            return l, sample_psi(k_psi, l, cfg.gamma)
+
+        self._tail_fn = jax.jit(tail_l_psi)
         # model-health reductions, dispatched ONLY when a metrics sink
         # is attached (obs.metrics_on()): the disabled path runs the
         # exact same program sequence as an uninstrumented build.
@@ -335,9 +350,13 @@ class StreamingHDP:
         # packed-slab casts, on device: the H2D copy moves packed bytes
         # and widens to the sampler's int32 there; the swept block
         # narrows before the D2H write-back. Exact for values < K.
-        self._widen_fn = jax.jit(lambda z: z.astype(jnp.int32))
+        self._widen_fn = jax.jit(widen_z)
         _zdt = self.z_dtype
-        self._narrow_fn = jax.jit(lambda z: z.astype(_zdt))
+
+        def narrow_z(z):
+            return z.astype(_zdt)
+
+        self._narrow_fn = jax.jit(narrow_z)
         # data-parallel lane mode: row-shard every block over the first
         # n_devices jax devices; the per-lane sweeps are plain per-device
         # jits (no shard_map, no collectives — placement follows the
@@ -584,10 +603,6 @@ class StreamingHDP:
         # run.
         health = obs.metrics_on()
         dn_nnz = jnp.zeros((), jnp.int32) if health else None
-        # driver-side wall per phase (train.phase_ms counters — the
-        # dashboard's phase-fraction bar); the metrics-off twin is a
-        # shared no-op.
-        clock = PhaseClock() if health else NULL_CLOCK
         key, k_phi, k_u, k_l, k_psi = self._split_fn(state.key)
         built_tables = ztables is None
         if built_tables:
@@ -630,8 +645,7 @@ class StreamingHDP:
         )
         try:
             if built_tables:
-                with tr.span("tables.build", cat="pipeline"), \
-                        clock.time("tables.build"):
+                with obs.phase("tables.build"):
                     jax.block_until_ready(ztables)
             if lane_mode:
                 # every lane holds its own replica of the (small) z-step
@@ -691,8 +705,7 @@ class StreamingHDP:
                 # the wait for the next staged block is the driver-side
                 # pipeline bubble: a long span here means H2D staging
                 # (or the disk z read upstream) is not keeping up.
-                with tr.span("stage_wait", cat="pipeline"), \
-                        clock.time("stage_wait"):
+                with obs.phase("stage_wait"):
                     item = next(staged_it, None)
                 if item is None:
                     break
@@ -706,8 +719,7 @@ class StreamingHDP:
                     # shard's sweep on its own device; the reducer
                     # thread merges and hands the swept shards to the
                     # write-back. The driver never waits on a device.
-                    with tr.span("sweep_submit", cat="pipeline", block=b), \
-                            clock.time("sweep_submit"):
+                    with obs.phase("sweep_submit", block=b):
                         for d, lane in enumerate(lanes):
                             lane.submit(
                                 b, ztab_lanes[d], z_b[d], tokens_b[d],
@@ -715,8 +727,7 @@ class StreamingHDP:
                                 jax.device_put(k_ub, lane.device))
                         reducer.submit(b)
                 else:
-                    with tr.span("sweep", cat="pipeline", block=b), \
-                            clock.time("sweep"):
+                    with obs.phase("sweep", block=b):
                         z_b, dn_c, dh_c = self._z_fn(
                             ztables, z_b, tokens_b, mask_b, state.psi, k_ub
                         )
@@ -726,8 +737,7 @@ class StreamingHDP:
                             dn_nnz = self._nnz_fn(dn_nnz, dn_c)
                     # narrow on device so the write-back D2H moves packed
                     # bytes (the slab store lands them as-is).
-                    with tr.span("wb_submit", cat="pipeline", block=b), \
-                            clock.time("wb_submit"):
+                    with obs.phase("wb_submit", block=b):
                         writer.submit(b, z_b if self.z_dtype == np.int32
                                       else self._narrow_fn(z_b))
                 done += 1
@@ -735,8 +745,7 @@ class StreamingHDP:
                 if (ckpt_dir and ckpt_every_blocks
                         and cursor < self.store.num_blocks
                         and cursor % ckpt_every_blocks == 0):
-                    with tr.span("checkpoint", cat="pipeline", block=b), \
-                            clock.time("checkpoint"):
+                    with obs.phase("checkpoint", block=b):
                         if lane_mode:
                             reducer.flush()  # statistic current in hold
                             n_run, dh_acc = hold["n_run"], hold["dh_acc"]
@@ -769,7 +778,7 @@ class StreamingHDP:
         if lane_mode:
             n_run, dh_acc, dn_nnz = (hold["n_run"], hold["dh_acc"],
                                      hold["dn_nnz"])
-        with tr.span("tail", cat="pipeline"), clock.time("tail"):
+        with obs.phase("tail"):
             l, psi = self._tail_fn(dh_acc, state.psi, k_l, k_psi)
         out = StreamingState(
             n=n_run, phi=phi_shard, varphi=varphi_shard, psi=psi, l=l,
@@ -777,12 +786,12 @@ class StreamingHDP:
         )
         lane_walls = ([(lane.d, lane.wall_s) for lane in lanes]
                       if lane_mode and health else None)
-        self._publish_health(out, dn_nnz, done, dh_acc=dh_acc, clock=clock,
+        self._publish_health(out, dn_nnz, done, dh_acc=dh_acc,
                              lane_walls=lane_walls)
         return out
 
     def _publish_health(self, state: StreamingState, dn_nnz, blocks_done,
-                        dh_acc=None, clock=None, lane_walls=None):
+                        dh_acc=None, lane_walls=None):
         """Per-iteration model-health metrics into the global registry.
 
         Cheap host-side counters/gauges are always maintained; the
@@ -828,10 +837,6 @@ class StreamingHDP:
                     self._diag = ConvergenceDiagnostics(
                         self.cfg, num_tokens=self.store.num_tokens)
                 self._diag.update(M, state.n, dh_acc, state.psi)
-        if clock is not None:
-            for phase, sec in clock.acc.items():
-                M.counter("train.phase_ms", phase=phase).inc(
-                    round(sec * 1e3, 3))
         obs.flush_metrics()
 
     def iteration_profiled(self, state: StreamingState, timers=None):

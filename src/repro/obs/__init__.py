@@ -10,8 +10,12 @@ Every subsystem publishes into the same two singletons:
     keeps the disabled path bitwise-identical to an uninstrumented run.
   * ``tracer()`` — the ``SpanTracer``. Disabled by default (every span
     call is one attribute check); ``enable_tracing`` starts recording
-    and fixes the output path, ``finalize`` writes the Chrome trace
-    JSON.
+    (Chrome trace JSON, plus ``repro.*`` ``jax.profiler`` annotations
+    for a profiler trace running alongside) and fixes the output path,
+    ``finalize`` writes the JSON.
+
+``phase(name)`` is the training pipeline's phase span: the tracer's
+span that also feeds ``train.phase_ms`` while a sink is attached.
 
 CLIs call ``setup(trace=..., metrics=...)`` after argparse (the
 ``--trace`` / ``--metrics`` flags, or the ``REPRO_TRACE`` /
@@ -49,6 +53,17 @@ def metrics_on() -> bool:
     """True when a JSONL sink is attached — the gate call sites use
     before computing anything *extra* just to publish it."""
     return _LOGGER is not None
+
+
+def phase(name: str, **args):
+    """One pipeline phase, as one ``with``: the tracer's span (category
+    ``pipeline``) that, while a metrics sink is attached, also adds its
+    wall milliseconds to the ``train.phase_ms{phase=name}`` counter (the
+    dashboard's phase-fraction bar). With neither on, the tracer's
+    shared no-op."""
+    add_ms = (_REGISTRY.counter("train.phase_ms", phase=name)
+              if _LOGGER is not None else None)
+    return _TRACER.span(name, "pipeline", add_ms=add_ms, **args)
 
 
 def enable_metrics(path: str, *, every_s: Optional[float] = None,
@@ -129,6 +144,7 @@ def finalize() -> dict:
     global _LOGGER
     out: dict = {}
     if _TRACER.enabled:
+        _TRACER.stop()  # nothing more lands: the counts below agree
         if _LOGGER is not None and _TRACER.dropped:
             _REGISTRY.gauge("obs.trace_dropped_events").set(_TRACER.dropped)
         path = _TRACER.save()
@@ -138,7 +154,6 @@ def finalize() -> dict:
             print(f"WARNING: tracer dropped {_TRACER.dropped} events "
                   "(bounded buffer full) — the saved trace is truncated",
                   file=sys.stderr)
-        _TRACER.stop()
     if _LOGGER is not None:
         if _LOGGER.suppressed:
             _REGISTRY.gauge("obs.metrics_suppressed_flushes").set(

@@ -51,16 +51,15 @@ Metric name schema (all under the ``train.`` prefix):
     50% means; naive segment variance, not spectral density — a cheap
     screen, |z| >> 2 flags a drifting chain, not a hypothesis test).
   * ``train.phase_ms{phase=...}`` (counters) — cumulative driver-side
-    wall milliseconds per pipeline phase (``PhaseClock``); the
-    dashboard renders their relative fractions.
+    wall milliseconds per pipeline phase (the phase spans,
+    ``repro.obs.phase``); the dashboard renders their relative
+    fractions.
 
 ``launch/dashboard.py`` renders these live; ``benchmarks/check_health.py``
 asserts them on a seeded short chain as a hard CI gate.
 """
 
 from __future__ import annotations
-
-import time
 
 import jax
 import jax.numpy as jnp
@@ -165,72 +164,6 @@ def make_topic_fn(top_words: int):
         return live, entropy, jnp.max(mass), top
 
     return fn
-
-
-# -- driver-side phase wall-clock (feeds the dashboard's fractions) ----------
-
-class _ClockSpan:
-    __slots__ = ("_acc", "_name", "_t0")
-
-    def __init__(self, acc, name):
-        self._acc = acc
-        self._name = name
-
-    def __enter__(self):
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc):
-        self._acc[self._name] = (self._acc.get(self._name, 0.0)
-                                 + time.perf_counter() - self._t0)
-        return False
-
-
-class _NullClockSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_CLOCK_SPAN = _NullClockSpan()
-
-
-class PhaseClock:
-    """Accumulates driver-side wall seconds per pipeline phase into
-    ``acc`` — published as ``train.phase_ms{phase=...}`` counters at
-    iteration end. Unlike the tracer's spans this is a plain running
-    sum, cheap enough to keep per-iteration; unlike ``PhaseTimers`` it
-    measures the *overlapped* driver (dispatch + waits), which is what
-    the dashboard's phase-fraction bar should show."""
-
-    __slots__ = ("acc",)
-
-    def __init__(self):
-        self.acc: dict[str, float] = {}
-
-    def time(self, name: str):
-        return _ClockSpan(self.acc, name)
-
-
-class _NullClock:
-    """Shared no-op twin for the metrics-off path (same shape as
-    ``PhaseClock`` so call sites never branch)."""
-
-    __slots__ = ()
-
-    @property
-    def acc(self):
-        return {}
-
-    def time(self, name: str):
-        return _NULL_CLOCK_SPAN
-
-
-NULL_CLOCK = _NullClock()
 
 
 # -- the per-chain observatory ------------------------------------------------

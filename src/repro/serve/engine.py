@@ -62,10 +62,13 @@ def _engine_step(snap, tokens, mask, z, seeds, sweeps, base_key, *,
     """
     length = tokens.shape[1]
     if has_fresh:
-        u0 = F.sweep_uniforms(base_key, seeds, jnp.zeros_like(seeds), length)
-        z_init = F.init_z(tokens, mask, u0, snap.fpack, snap.ipack)
-        z = jnp.where((sweeps == 0)[:, None], z_init, z)
-    u = F.sweep_uniforms(base_key, seeds, sweeps + 1, length)
+        with jax.named_scope("init"):
+            u0 = F.sweep_uniforms(base_key, seeds, jnp.zeros_like(seeds),
+                                  length)
+            z_init = F.init_z(tokens, mask, u0, snap.fpack, snap.ipack)
+            z = jnp.where((sweeps == 0)[:, None], z_init, z)
+    with jax.named_scope("uniforms"):
+        u = F.sweep_uniforms(base_key, seeds, sweeps + 1, length)
     return C.z_step_conformant(
         impl, tokens, mask, z, u, snap.q_a, snap.fpack, snap.ipack,
         kk=snap.K,
@@ -142,6 +145,8 @@ class _Pending:
 class EngineStats:
     completed: int = 0
     steps: int = 0
+    host_syncs: int = 0        # blocking device reads (retirement)
+    live_slot_sweeps: int = 0  # occupied slots swept, summed over steps
     wall_s: float = 0.0
     latencies_s: list = field(default_factory=list)
     latencies_dropped: int = 0  # oldest samples evicted by the window cap
@@ -165,6 +170,8 @@ class EngineStats:
         return {
             "completed": self.completed,
             "steps": self.steps,
+            "host_syncs": self.host_syncs,
+            "live_slot_sweeps": self.live_slot_sweeps,
             "docs_per_s": round(self.completed / max(self.wall_s, 1e-9), 2),
             "p50_latency_ms": round(float(np.percentile(lat, 50)), 2)
             if len(lat) else None,
@@ -278,11 +285,12 @@ class ServeEngine:
         if tr.enabled:
             tr.async_begin("request.queued", self._aid(rid), cat="serve",
                            bucket=bucket, tag=self.trace_tag)
-        if self._packer is not None:
-            self._packer.submit((p, bucket))  # packs + enqueues off-thread
-        else:
-            self._pack(p, bucket)
-            self._queue[bucket].append(p)
+        with tr.span("engine.submit", cat="serve", bucket=bucket):
+            if self._packer is not None:
+                self._packer.submit((p, bucket))  # packs off-thread
+            else:
+                self._pack(p, bucket)
+                self._queue[bucket].append(p)
         return rid
 
     def _aid(self, rid: int) -> str:
@@ -294,7 +302,7 @@ class ServeEngine:
         q = self._queue[bucket]
         admitted = False
         tr = obs.tracer()
-        hist = obs.metrics().histogram("serve.queue_wait_ms", bucket=bucket)
+        hist = None
         for s in range(self.slots):
             if pool.req[s] is not None or not q:
                 continue
@@ -309,6 +317,9 @@ class ServeEngine:
             pool.req[s] = p.rid
             p.row_tokens = p.row_mask = None
             p.admit_t = time.perf_counter()
+            if hist is None:
+                hist = obs.metrics().histogram("serve.queue_wait_ms",
+                                               bucket=bucket)
             hist.observe((p.admit_t - p.submit_t) * 1e3)
             if tr.enabled:
                 aid = self._aid(p.rid)
@@ -324,28 +335,35 @@ class ServeEngine:
                 if pool.req[s] is not None and pool.sweeps[s] >= self.burnin]
         if not done:
             return
-        # mixtures from the last sweep's emitted histograms (pool.m is
-        # set by every step; retirement requires >= 1 sweep).
-        theta = np.asarray(self._theta_fn(
-            pool.m, self.snap.psi, self.snap.alpha,
-        ))
-        now = time.perf_counter()
         tr = obs.tracer()
-        hist = obs.metrics().histogram("serve.service_ms", bucket=pool.length)
-        for s in done:
-            # evict the request entirely: a long-lived engine must not
-            # accumulate per-request state (tokens, theta) forever.
-            p = self._reqs.pop(pool.req[s])
-            self._completed[p.rid] = theta[s]
-            self.stats.completed += 1
-            self.stats.record_latency(now - p.submit_t)
-            if p.admit_t is not None:
-                hist.observe((now - p.admit_t) * 1e3)
-            if tr.enabled:
-                tr.async_end("request.inflight", self._aid(p.rid),
-                             cat="serve")
-            pool.req[s] = None
-            pool.mask[s] = False
+        with tr.span("engine.retire", cat="serve", bucket=pool.length,
+                     n=len(done)):
+            # mixtures from the last sweep's emitted histograms (pool.m
+            # is set by every step; retirement requires >= 1 sweep). The
+            # read blocks until the step that retires them has run.
+            with tr.span("engine.retire_wait", cat="serve"):
+                theta = np.asarray(self._theta_fn(
+                    pool.m, self.snap.psi, self.snap.alpha,
+                ))
+            self.stats.host_syncs += 1
+            now = time.perf_counter()
+            hist = obs.metrics().histogram("serve.service_ms",
+                                           bucket=pool.length)
+            for s in done:
+                # evict the request entirely: a long-lived engine must
+                # not accumulate per-request state (tokens, theta)
+                # forever.
+                p = self._reqs.pop(pool.req[s])
+                self._completed[p.rid] = theta[s]
+                self.stats.completed += 1
+                self.stats.record_latency(now - p.submit_t)
+                if p.admit_t is not None:
+                    hist.observe((now - p.admit_t) * 1e3)
+                if tr.enabled:
+                    tr.async_end("request.inflight", self._aid(p.rid),
+                                 cat="serve")
+                pool.req[s] = None
+                pool.mask[s] = False
         # host masks changed (freed rows go inert); the device twin is
         # refreshed lazily at the next upload — stale True rows only cost
         # wasted sweep lanes, never correctness (they are re-initialized
@@ -356,31 +374,37 @@ class ServeEngine:
         """Admit, sweep every bucket with in-flight work, retire.
         Returns False when nothing is in flight and the queue is empty."""
         busy = False
+        tr = obs.tracer()
         for bucket in self.buckets:
             if self._queue[bucket] and bucket not in self._pools:
                 self._pools[bucket] = _Slots.empty(self.slots, bucket)
             pool = self._pools.get(bucket)
             if pool is None:
                 continue
-            self._admit(pool, bucket)
+            if self._queue[bucket]:
+                with tr.span("engine.admit", cat="serve", bucket=bucket):
+                    self._admit(pool, bucket)
             active = any(r is not None for r in pool.req)
             if not active:
                 continue
             busy = True
             has_fresh = any(r is not None and pool.sweeps[s] == 0
                             for s, r in enumerate(pool.req))
-            with obs.tracer().span("engine_step", cat="serve",
-                                   bucket=bucket, tag=self.trace_tag):
-                d_tokens, d_mask, d_seeds = pool.device_batch()
+            with tr.span("engine_step", cat="serve", bucket=bucket,
+                         tag=self.trace_tag):
+                with tr.span("engine.upload", cat="serve"):
+                    d_tokens, d_mask, d_seeds = pool.device_batch()
+                    d_sweeps = _upload(pool.sweeps)
                 pool.z, pool.m = self._step_fn(
                     self.snap, d_tokens, d_mask, pool.z, d_seeds,
-                    _upload(pool.sweeps), self.base_key, impl=self.impl,
+                    d_sweeps, self.base_key, impl=self.impl,
                     has_fresh=has_fresh,
                 )
             live = np.array([r is not None for r in pool.req])
             pool.sweeps[live] += 1
             pool.steps += 1
             self.stats.steps += 1
+            self.stats.live_slot_sweeps += int(live.sum())
             self.stats.shapes.add((self.slots, bucket))
             self._retire(pool)
         return busy or any(self._queue.values())

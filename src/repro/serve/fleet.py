@@ -45,6 +45,8 @@ from repro.serve.router import AdmissionRouter, Task
 from repro.serve.snapshot import ModelSnapshot
 
 _PINNED = -1  # engine key for a fleet constructed from a bare snapshot
+# ``EngineStats`` counters that worker and fleet summaries add up
+_COUNTS = ("steps", "host_syncs", "live_slot_sweeps")
 
 
 def _localize(snap: ModelSnapshot, device) -> ModelSnapshot:
@@ -65,7 +67,7 @@ class _Worker(threading.Thread):
         self.engines: dict[int, ServeEngine] = {}
         self.tasks: dict[tuple[int, int], Task] = {}  # (version, rid)
         self.completed = 0
-        self.steps_retired = 0          # steps of already-discarded engines
+        self.retired = dict.fromkeys(_COUNTS, 0)  # discarded engines
         self.swaps = 0
         self.error: Optional[BaseException] = None
         self._warm_bucket: Optional[int] = None
@@ -91,7 +93,8 @@ class _Worker(threading.Thread):
             if v != current and eng.in_flight() == 0:
                 if eng.stats.steps:
                     self.swaps += 1
-                self.steps_retired += eng.stats.steps
+                for k in _COUNTS:
+                    self.retired[k] += getattr(eng.stats, k)
                 eng.close()
                 del self.engines[v]
 
@@ -155,7 +158,8 @@ class _Worker(threading.Thread):
         return {
             "worker": self.wid,
             "completed": self.completed,
-            "steps": self.steps_retired + sum(e.stats.steps for e in engines),
+            **{k: self.retired[k] + sum(getattr(e.stats, k) for e in engines)
+               for k in _COUNTS},
             "snapshot_swaps": self.swaps,
             "compiled_shapes": sorted(
                 {s for e in engines for s in list(e.stats.shapes)}
@@ -354,7 +358,7 @@ class ServeFleet:
             "workers": len(self.workers),
             "ensemble": self.ensemble,
             "completed": completed,
-            "steps": sum(s["steps"] for s in per_worker),
+            **{k: sum(s[k] for s in per_worker) for k in _COUNTS},
             "snapshot_swaps": sum(s["snapshot_swaps"] for s in per_worker),
             "wall_s": round(wall, 3),
             "docs_per_s": round(completed / max(wall, 1e-9), 2),

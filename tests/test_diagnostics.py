@@ -11,8 +11,7 @@ import math
 import numpy as np
 import pytest
 
-from repro.obs.diagnostics import (ConvergenceDiagnostics, NULL_CLOCK,
-                                   PhaseClock, ess, geweke,
+from repro.obs.diagnostics import (ConvergenceDiagnostics, ess, geweke,
                                    make_joint_loglik_fn, make_topic_fn)
 from repro.obs.metrics import MetricsRegistry
 
@@ -174,21 +173,36 @@ def test_diagnostics_window_bounds_chain():
     assert len(diag._kstar_chain) == 5
 
 
-# -- PhaseClock ---------------------------------------------------------------
+# -- the phase span (train.phase_ms) -------------------------------------------
 
-def test_phase_clock_accumulates_and_null_is_empty():
-    clock = PhaseClock()
-    with clock.time("sweep"):
-        pass
-    with clock.time("sweep"):
-        pass
-    with clock.time("tail"):
-        pass
-    assert set(clock.acc) == {"sweep", "tail"}
-    assert all(v >= 0 for v in clock.acc.values())
-    with NULL_CLOCK.time("anything"):
-        pass
-    assert NULL_CLOCK.acc == {}
+def test_phase_span_accumulates_and_off_is_noop(tmp_path):
+    from repro import obs
+    from repro.obs.trace import _NULL_SPAN
+
+    obs.reset_for_tests()
+    try:
+        # neither a sink nor the tracer: the tracer's shared no-op
+        assert obs.phase("sweep", block=0) is _NULL_SPAN
+        obs.enable_metrics(str(tmp_path / "m.jsonl"))
+        for _ in range(2):
+            with obs.phase("sweep", block=0):
+                pass
+        with obs.phase("tail"):
+            pass
+        M = obs.metrics()
+        assert M.get("train.phase_ms", phase="sweep").value >= 0
+        assert M.get("train.phase_ms", phase="tail") is not None
+        # a sink alone feeds the counter and records no span
+        assert not obs.tracer().enabled and obs.tracer().events() == []
+        obs.enable_tracing()
+        with obs.phase("stage_wait"):
+            pass
+        (x,) = [e for e in obs.tracer().events()
+                if e["ph"] == "X" and e["cat"] != "gc"]
+        assert (x["name"], x["cat"]) == ("stage_wait", "pipeline")
+        assert M.get("train.phase_ms", phase="stage_wait") is not None
+    finally:
+        obs.reset_for_tests()
 
 
 # -- end-to-end: the observatory on a real streaming chain --------------------

@@ -292,6 +292,12 @@ def test_fleet_backpressure_and_stats(trained):
     assert s["p95_latency_ms"] >= s["p50_latency_ms"]
     assert sum(w["completed"] for w in s["per_worker"]) == n
     assert len(s["per_worker"]) == 2
+    # engine counters add up over workers: each request's slot is swept
+    # burnin times, and every step that retires reads the device once
+    assert s["live_slot_sweeps"] == n * BURNIN
+    assert s["live_slot_sweeps"] == sum(w["live_slot_sweeps"]
+                                        for w in s["per_worker"])
+    assert 0 < s["host_syncs"] <= s["steps"]
 
 
 def test_fleet_ensemble_backpressure_bounded(trained):

@@ -10,6 +10,7 @@ consistent (histogram counts match completions, SLO ok+miss ==
 completed, thread tracks are correctly named).
 """
 
+import gc
 import json
 import os
 import tempfile
@@ -170,17 +171,27 @@ def test_metrics_logger_rate_limit(tmp_path):
 
 # -- span tracer --------------------------------------------------------------
 
+@pytest.fixture
+def no_auto_gc():
+    """Automatic collections off: a recording tracer adds a
+    ``python.gc`` span for each, which exact event counts would see."""
+    was = gc.isenabled()
+    gc.disable()
+    yield
+    if was:
+        gc.enable()
+
+
 def test_disabled_tracer_is_noop_singleton():
     tr = SpanTracer()
     assert tr.span("x") is _NULL_SPAN
     assert tr.span("y", cat="c", block=1) is _NULL_SPAN
-    tr.instant("i")
     tr.async_begin("a", 1)
     tr.async_end("a", 1)
     assert tr.events() == []
 
 
-def test_tracer_records_complete_events(tmp_path):
+def test_tracer_records_complete_events(tmp_path, no_auto_gc):
     tr = SpanTracer()
     tr.start()
     with tr.span("work", cat="test", block=3):
@@ -229,7 +240,7 @@ def test_tracer_thread_tracks():
     assert by_span["main"] == threading.current_thread().name
 
 
-def test_tracer_drops_past_capacity():
+def test_tracer_drops_past_capacity(no_auto_gc):
     tr = SpanTracer(max_events=3)
     tr.start()
     for i in range(10):
@@ -266,7 +277,7 @@ def test_phase_timers_reject_nesting():
     assert t.counts["after"] == 1
 
 
-def test_phase_timers_forward_to_tracer():
+def test_phase_timers_forward_to_tracer(no_auto_gc):
     tr = obs.enable_tracing()
     t = PhaseTimers()
     with t.phase("sweep"):

@@ -217,6 +217,73 @@ def test_engine_stats_and_continuous_admission(snap, trained):
     assert s["compiled_shapes"] == [(2, 32)]
 
 
+def test_engine_spans_and_counters(snap, trained):
+    """Traced, the engine records one ``engine_step`` (with its bucket)
+    per bucket sweep with ``engine.upload`` inside it, and
+    ``engine.retire_wait`` inside ``engine.retire``; a drained run swept
+    each request's slot exactly ``burnin`` times, one blocking read per
+    retirement."""
+    from collections import Counter
+
+    from repro import obs
+
+    _, _, (q_tokens, q_mask) = trained
+    docs = _docs_from(q_tokens, q_mask)
+    eng = ServeEngine(snap, slots=2, burnin=BURNIN, impl="sparse",
+                      buckets=(16, 32), base_key=jax.random.key(0))
+    obs.reset_for_tests()
+    obs.enable_tracing()
+    try:
+        for i, doc in enumerate(docs):
+            eng.submit(doc, seed=i)
+        out = eng.run()
+        evs = [e for e in obs.tracer().events() if e["ph"] == "X"]
+    finally:
+        obs.reset_for_tests()
+    assert len(out) == len(docs)
+    names = Counter(e["name"] for e in evs)
+    steps = [e for e in evs if e["name"] == "engine_step"]
+    assert len(steps) == eng.stats.steps
+    assert Counter(e["args"]["bucket"] for e in steps) == {
+        b: pool.steps for b, pool in eng._pools.items()}
+    assert names["engine.submit"] == len(docs)
+    assert names["engine.upload"] == len(steps)
+    assert names["engine.retire"] == names["engine.retire_wait"] \
+        == eng.stats.host_syncs > 0
+
+    def inside(name, outer):
+        eps = 1e-3  # the events' microseconds are rounded to ns
+        for e in evs:
+            if e["name"] != name:
+                continue
+            assert any(o["name"] == outer and o["tid"] == e["tid"]
+                       and o["ts"] - eps <= e["ts"]
+                       and e["ts"] + e["dur"] <= o["ts"] + o["dur"] + eps
+                       for o in evs), (name, outer)
+
+    inside("engine.upload", "engine_step")
+    inside("engine.retire_wait", "engine.retire")
+    assert eng.stats.live_slot_sweeps == eng.stats.completed * BURNIN
+    assert eng.stats.completed == len(docs)
+    s = eng.stats.summary()
+    assert (s["host_syncs"], s["live_slot_sweeps"]) == (
+        eng.stats.host_syncs, eng.stats.live_slot_sweeps)
+
+
+@pytest.mark.parametrize("scope", ["init", "uniforms"])
+def test_engine_step_named_scopes(snap, scope):
+    """The engine step's named scopes are op metadata of its module."""
+    from repro.serve.engine import _engine_step
+
+    b, length = 2, 16
+    low = jax.jit(_engine_step, static_argnames=("impl", "has_fresh")).lower(
+        snap, np.zeros((b, length), np.int32), np.ones((b, length), bool),
+        np.zeros((b, length), np.int32), np.arange(b, dtype=np.int32),
+        np.zeros((b,), np.int32), jax.random.key(0), impl="sparse",
+        has_fresh=True)
+    assert f"/{scope}/" in low.as_text(debug_info=True)
+
+
 def test_engine_rejects_duplicate_seed_and_drains_results(snap, trained):
     _, _, (q_tokens, q_mask) = trained
     docs = _docs_from(q_tokens, q_mask)
