@@ -11,10 +11,11 @@ tokens, table width W=128), with the compiled Pallas z-step:
   check  n equals a from-scratch integer recount of z; on 64 documents,
          from random topics and with the same uniforms, the compiled
          kernel's z equals the ``hdp_z_ref`` oracle's bitwise, both for
-         the kernel-prologue alias build that training runs and for the
-         epilogue tables that serving runs (on a mismatch it prints the
-         fraction and compares the alias rows the chip builds with the
-         oracle's before failing); its m equals a recount of its z. The
+         the per-word epilogue tables that training and serving sweep on
+         and for the kernel-prologue alias build (``alias_in_kernel=
+         "on"``); on a mismatch it prints the fraction and compares the
+         alias rows the chip builds with the oracle's before failing;
+         its m equals a recount of its z. The
          joint log-likelihood of every iteration is printed as
          information
   serve  a snapshot exported from the trained state folds in 16

@@ -72,8 +72,11 @@ class HDPConfig(NamedTuple):
     hist_cap: int = 256      # P: per-(doc,topic) count cap for the l histogram
     unroll_z: bool = False   # unroll the in-document sweep (cost probes)
     alias_in_kernel: str = "auto"  # pallas only: build term-(a) alias
-    #                          tables inside the z kernel (auto|on|off;
-    #                          auto = on for compiled TPU, off elsewhere)
+    #                          rows inside the z kernel, once per live
+    #                          token (auto|on|off). auto = off: per-word
+    #                          tables built once per iteration are faster
+    #                          on the chip (kernels/hdp_z/ops.py
+    #                          resolve_alias_in_kernel)
     ppu_nnz_budget: int | None = None  # doubly-sparse PPU Phi draw over
     #                          at most this many non-zero n cells (must
     #                          bound nnz(n); corpus token count always

@@ -26,9 +26,10 @@ within each Gibbs iteration:
     ``REPRO_BLOCK_SPARSE_TABLES`` env var) the alias tables are built
     only for vocabulary rows actually present in the corpus
     (``ShardedCorpusStore.vocab_ids``; "auto" enables this below 50%
-    vocab coverage), and with ``HDPConfig.alias_in_kernel`` the pallas
-    impl skips the table materialization entirely (the kernel-prologue
-    alias build — kernels/hdp_z/hdp_z.py);
+    vocab coverage); ``HDPConfig.alias_in_kernel="on"`` instead has the
+    pallas kernel rebuild each live token's alias row from raw supports
+    (the kernel-prologue build, kernels/hdp_z/hdp_z.py), which costs
+    more on the chip than building every word's row once here;
   * per-block sufficient statistics merge as *deltas*: the z-sweep
     emits its per-document histogram m from the sweep carry and the
     block's exact integer delta to the topic-word statistic, so the hot
@@ -327,6 +328,18 @@ class StreamingHDP:
             self._phi_fn = functools.partial(self._masked_phi, mfn)
         else:
             self._phi_fn = jax.jit(sharded.phi_tables_fn())
+        # alias rows one table build makes, from shapes alone
+        # (train.alias_rows_built): one per vocabulary row, or the masked
+        # build's cap per model shard; with the kernel-prologue build,
+        # one per live token, since the kernel rebuilds a word's row at
+        # every occurrence. The dense impl builds none.
+        if sharded.alias_in_kernel:
+            self._alias_rows = store.num_tokens
+        elif enable_mask:
+            m = sharded.mesh.shape[sharded.model_axis]
+            self._alias_rows = m * min(cap, self.cfg.V // m)
+        else:
+            self._alias_rows = 0 if self.cfg.z_impl == "dense" else self.cfg.V
         self._z_fn = jax.jit(sharded.z_block_fn(), donate_argnums=(1,))
         # each program of the iteration is a named function, so its
         # module reads jit_<name> in a profile: one jitted dispatch per
@@ -618,6 +631,8 @@ class StreamingHDP:
                 state.n, state.psi, k_phi
             )
             obs.metrics().counter("train.alias_rebuilds").inc()
+            obs.metrics().counter("train.alias_rows_built").inc(
+                self._alias_rows)
         else:
             phi_shard, varphi_shard, ztables = ztables
         if n_run is None:

@@ -59,8 +59,14 @@ raw supports — vals (V, W) f32, ids (V, W) i32 — plus apsi = alpha*psi
 two raw (W,) rows (half the packed-table bytes), rebuilds
 ``wa = vals * apsi[ids]``, ``q_a = sum(wa)``, and the alias partition
 via ``core.alias.alias_build_row_onehot`` (the Pallas-safe one-hot twin
-of ``alias_build`` — bitwise-equal pairing, no scatters). The (V, 2, W)
-alias-table materialization to HBM never happens.
+of ``alias_build``, no scatters). The (V, 2, W) alias-table
+materialization to HBM never happens, but the build runs once per live
+token instead of once per word: five (W, W) transposes and about a dozen
+(W, W) compare-and-reduce passes in the token loop. The packed tables
+are the default (``resolve_alias_in_kernel``): on one v5e chip a live
+token costs 2.5-2.7 us with the prologue build and 1.6-1.7 us on packed
+tables, whose build, once per word, adds about 0.1 s an iteration at
+V=90k (PERF.md).
 
 Compiled (not interpret) mode needs W to be a multiple of 128: the
 lane gathers work on whole 128-lane registers.

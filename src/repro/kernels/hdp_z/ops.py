@@ -18,7 +18,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-from repro.core.alias import alias_build, prefix_sum
+from repro.core.alias import alias_build_onehot, prefix_sum
 from repro.core.hdp import delta_n
 from repro.kernels.hdp_z.hdp_z import hdp_z_pallas, resolve_interpret
 from repro.kernels.hdp_z.ref import hdp_z_ref, hdp_z_ref_prologue
@@ -33,15 +33,20 @@ def resolve_alias_in_kernel(
     """Resolve whether the alias partition is built in the kernel prologue.
 
     Precedence: an explicit ``"on"``/``"off"`` (or bool) wins; else the
-    ``REPRO_ALIAS_IN_KERNEL`` env var; else ``"auto"`` = on exactly when
-    the kernel is compiled (not interpret mode) — the prologue's win is
-    skipping the (V, 2, W) table HBM round-trip, which only exists on
-    real hardware; interpret mode keeps the epilogue-fused oracle path
-    unless forced on for conformance runs.
+    ``REPRO_ALIAS_IN_KERNEL`` env var; else ``"auto"`` = off on every
+    backend: the kernel sweeps on per-word ``(q_a, fpack, ipack)``
+    tables built once per iteration. The prologue rebuilds a word's
+    alias partition (O(W^2) compares) for every live token instead of
+    once per word, which costs more than the (V, 2, W) tables it saves:
+    on one v5e chip a live token took 2.5-2.7 us in the kernel with the
+    prologue and 1.6-1.7 us on per-word tables, whose build adds about
+    0.1 s an iteration at V=90k (PERF.md). ``interpret`` no longer
+    changes the result; it stays in the signature for the existing
+    callers.
 
     The prologue consumes raw f32 supports, so it composes with
     ``compact=False`` only: an explicit ``"on"`` with compact tables
-    raises; env/auto resolution silently degrades to the epilogue.
+    raises; env resolution silently degrades to the epilogue.
     """
     if isinstance(explicit, bool):
         on = explicit
@@ -61,7 +66,7 @@ def resolve_alias_in_kernel(
     env = os.environ.get("REPRO_ALIAS_IN_KERNEL")
     if env is not None:
         return (env.strip().lower() not in _FALSY) and not compact
-    return (not interpret) and not compact
+    return False
 
 
 def _word_supports(pt: jax.Array, w: int, order: str):
@@ -129,7 +134,7 @@ def build_word_sparse_tables(
     vals, ids = _word_supports(phi.T, w, order)
     wa = vals * (jnp.float32(alpha) * psi)[ids]
     q_a = prefix_sum(wa)[..., -1]
-    aprob, aalias = alias_build(wa)
+    aprob, aalias = alias_build_onehot(wa)
     if compact:
         fpack = jnp.stack(
             [vals.astype(jnp.bfloat16), aprob.astype(jnp.bfloat16)], axis=1
@@ -177,7 +182,7 @@ def build_word_sparse_tables_masked(
     vals, ids = _word_supports(phi.T[rows], w, order)
     wa = vals * (jnp.float32(alpha) * psi)[ids]
     q_a_sub = prefix_sum(wa)[..., -1]
-    aprob, aalias = alias_build(wa)
+    aprob, aalias = alias_build_onehot(wa)
     if compact:
         fpack_sub = jnp.stack(
             [vals.astype(jnp.bfloat16), aprob.astype(jnp.bfloat16)], axis=1
@@ -274,10 +279,10 @@ def z_step_pallas(
     ``build_word_sparse_tables``); the kernel runs compiled on a TPU and
     in interpret mode elsewhere (``resolve_interpret``);
     ``alias_in_kernel`` ("auto"/"on"/"off", see
-    ``resolve_alias_in_kernel``) selects the kernel-prologue alias
-    build over the epilogue-fused tables. Returns ``(z_new, m)`` like
-    every z-step (core/hdp.py docstring), plus the (K, V) ``delta_n``
-    when ``emit_delta=True``."""
+    ``resolve_alias_in_kernel``; "auto" is off) selects the
+    kernel-prologue alias build over the epilogue-fused tables. Returns
+    ``(z_new, m)`` like every z-step (core/hdp.py docstring), plus the
+    (K, V) ``delta_n`` when ``emit_delta=True``."""
     interp = resolve_interpret()
     return _z_step_pallas_fused(
         tokens, mask, z, phi, psi, alpha, uniforms,

@@ -186,8 +186,9 @@ def test_alias_in_kernel_resolver():
     assert r("off", interpret=False) is False
     assert r(True, interpret=True) is True
     assert r(False, interpret=False) is False
-    # auto: on exactly when compiled, never with compact tables
-    assert r("auto", interpret=False) is True
+    # auto: off on every backend (per-word tables are the faster path
+    # on the chip), never on with compact tables
+    assert r("auto", interpret=False) is False
     assert r("auto", interpret=True) is False
     assert r("auto", interpret=False, compact=True) is False
     # explicit on + compact is a contradiction, not a silent downgrade

@@ -669,6 +669,52 @@ def test_block_sparse_env_var_and_validation(rng, monkeypatch):
         StreamingHDP(sh, store, block_sparse_tables="maybe")
 
 
+def test_pallas_default_builds_word_tables(rng, monkeypatch):
+    """Under the compiled kernel's default the pallas sampler sweeps on
+    per-word tables, so the block-sparse build is available again and
+    "auto" engages it below 50% vocabulary coverage."""
+    from repro.kernels.hdp_z import ops as zops
+
+    monkeypatch.delenv("REPRO_ALIAS_IN_KERNEL", raising=False)
+    monkeypatch.setattr(zops, "resolve_interpret", lambda: False)
+    corpus, mesh, cfg, _ = make_setup(rng, D=8, impl="pallas", V=400)
+    sh = ShardedHDP(mesh, cfg)
+    assert sh.alias_in_kernel is False
+    assert sh.supports_masked_tables()
+    store = ShardedCorpusStore.from_corpus(corpus, block_docs=8)
+    assert store.vocab_coverage < 0.5
+    assert StreamingHDP(sh, store).block_sparse_tables is True
+    assert StreamingHDP(sh, store,
+                        block_sparse_tables="on").block_sparse_tables
+
+
+@pytest.mark.parametrize("mode", ["tables", "masked", "prologue"])
+def test_alias_rows_built_counter(rng, mode):
+    """``train.alias_rows_built`` adds, per table build, the alias rows
+    built, read from shapes: V on per-word tables, the block-sparse
+    cap when masked, and the live tokens when the kernel rebuilds each
+    token's row in its prologue."""
+    from repro import obs
+
+    corpus, mesh, cfg, _ = make_setup(rng, D=16, impl="pallas", V=96)
+    cfg = cfg._replace(alias_in_kernel="on" if mode == "prologue" else "off")
+    sh = ShardedHDP(mesh, cfg)
+    store = ShardedCorpusStore.from_corpus(corpus, block_docs=8)
+    stream = StreamingHDP(
+        sh, store, block_sparse_tables="on" if mode == "masked" else "off")
+    want = {"tables": cfg.V, "masked": int(store.vocab_ids().size),
+            "prologue": store.num_tokens}[mode]
+    assert 0 < want and (mode != "masked" or want < cfg.V)
+    rows = obs.metrics().counter("train.alias_rows_built")
+    swept = obs.metrics().counter("train.tokens_swept")
+    rows0, swept0 = rows.value, swept.value
+    st = stream.init_state(jax.random.key(0))
+    for _ in range(2):
+        st = stream.iteration(st)
+    assert rows.value - rows0 == 2 * want
+    assert swept.value - swept0 == 2 * store.num_tokens
+
+
 def test_budgeted_ppu_streaming_bitwise_equals_monolithic(rng):
     """The doubly-sparse budgeted PPU draw is a different uniform stream
     than the dense draw, but it must be the SAME stream on the monolithic

@@ -6,9 +6,11 @@ compiler would refuse (unaligned tiles, too much VMEM or SMEM, ops Mosaic
 cannot lower), which interpret mode never shows. Nothing here runs the
 kernel. Shapes are the main path's at two widths: the AP cell (K=1000,
 V=7168, L=512) and PubMed width (K=1000, V=90112, L=256), each with the
-kernel-prologue alias build on (what ``alias_in_kernel=auto`` picks on a
-TPU) and off (the epilogue tables serving uses), plus the training delta
-path (kernel, then the XLA ``delta_n`` scatter).
+kernel-prologue alias build on (``alias_in_kernel="on"``) and off (the
+per-word tables training and serving sweep on by default), plus the
+training delta path (kernel, then the XLA ``delta_n`` scatter) and the
+training table build that makes those per-word tables once per
+iteration.
 
 The topology is described inside a module-scoped fixture, never at
 import time: only one process may load the TPU library, and collection
@@ -24,6 +26,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.hdp import delta_n
 from repro.kernels.hdp_z.hdp_z import hdp_z_pallas
+from repro.kernels.hdp_z.ops import build_word_sparse_tables_masked
 
 K, W, DOCS = 1000, 128, 64
 WIDTHS = {"ap": (7168, 512), "pubmed": (90112, 256)}
@@ -106,12 +109,35 @@ def test_hdp_z_compiles_for_v5e(one_chip, no_compile_cache, width,
 def test_training_delta_path_compiles_for_v5e(one_chip, no_compile_cache):
     """The delta path training takes on the chip: the kernel emits
     (z, m), then ``delta_n`` scatters the (K, V) update in XLA."""
-    compiled = _compile(one_chip, "ap", in_kernel=True, with_delta=True)
+    compiled = _compile(one_chip, "ap", in_kernel=False, with_delta=True)
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "scatter" in text
     v, _ = WIDTHS["ap"]
     assert compiled.memory_analysis().output_size_in_bytes >= K * v * 4
+
+
+def test_training_table_build_compiles_for_v5e(one_chip, no_compile_cache):
+    """The per-word table build training runs once per iteration at
+    PubMed width: top-W supports, alias epilogue, block-sparse scatter
+    (cap = V, every row flagged). It must lower for the chip and fit its
+    16 GB with the (K, V) Phi it reads."""
+    v, _ = WIDTHS["pubmed"]
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def build(phi, psi, u_mask):
+        return build_word_sparse_tables_masked(phi, psi, 0.1, W, u_mask, v)
+
+    compiled = jax.jit(build).lower(
+        s((K, v), jnp.float32), s((K,), jnp.float32),
+        s((v,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.output_size_in_bytes >= v * 4 + 2 * v * 2 * W * 4
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes)
+    assert total < 16 * 10**9
 
 
 def test_compiled_kernel_refuses_unaligned_width():
