@@ -7,17 +7,20 @@ It reads the planes of the run's trace, the file ``Window.reduce`` reads
 (``trace.load``), once per run: the readers of ``metrics/`` share them.
 
   module time    the union of a module's operation intervals inside the
-                 window, summed over the device planes (an operation
-                 belongs to the module event it starts in, as in
-                 ``trace.device_ops``); ``other`` leaves out the
-                 kernel's own operations
+                 window, in chip-seconds summed over the device planes
+                 (an operation belongs to the module event it starts
+                 in, as in ``trace.device_ops``); ``other`` leaves out
+                 the kernel's own operations
   program idle   the stretches of the window with no operation on a
                  device, cut by the ``repro.*`` spans of the host thread
                  that holds the harness's ``hdp_bench.window``: each
                  piece goes to the innermost span over it, and pieces
                  under no such span to none. Spans of other threads
                  (the streaming pipeline's stagers, write-back) do not
-                 count. Averaged over the device planes, like busy time.
+                 count. A mean over the device planes, like busy time.
+
+A run whose trace holds fewer device planes than its cell has chips
+reads nothing here (``of``), as in ``readers.device_trace``.
 """
 
 from __future__ import annotations
@@ -85,12 +88,12 @@ def attribute(planes: list, kernel: str = "hdp_z"):
     """Module and program-idle seconds of the traced window; None where
     the trace has no window or no device operation.
 
-    Returns ``window_s``; ``module_s`` and ``module_other_s`` (module
-    name -> seconds, summed over devices); ``program_spans``, the count
-    of the window thread's ``repro.*`` spans inside the window; and,
-    averaged over devices, ``idle_s``, ``idle_in_program_s`` and
-    ``idle_by_span`` (innermost span name without the prefix -> idle
-    seconds).
+    Returns ``window_s``; ``devices``, the device planes with an
+    operation; ``module_s`` and ``module_other_s`` (module name ->
+    seconds, summed over devices); ``program_spans``, the count of the
+    window thread's ``repro.*`` spans inside the window; and, averaged
+    over devices, ``idle_s``, ``idle_in_program_s`` and ``idle_by_span``
+    (innermost span name without the prefix -> idle seconds).
     """
     win = window_thread(planes)
     devs = T.device_ops(planes)
@@ -127,7 +130,8 @@ def attribute(planes: list, kernel: str = "hdp_z"):
             idle_by[name] = idle_by.get(name, 0.0) + (e - s) * 1e-9
     n = len(devs)
     idle_by = {k: v / n for k, v in idle_by.items()}
-    return {"window_s": (hi - lo) * 1e-9, "idle_s": idle_s / n,
+    return {"window_s": (hi - lo) * 1e-9, "devices": n,
+            "idle_s": idle_s / n,
             "module_s": module_s, "module_other_s": other_s,
             "program_spans": len(spans),
             "idle_in_program_s": sum(idle_by.values()),
@@ -158,7 +162,8 @@ def trace_file(run):
 
 def of(run):
     """``attribute`` of this run's trace, loaded and computed once per
-    trace file."""
+    trace file; None where it holds fewer device planes than the cell
+    has chips."""
     path = trace_file(run)
     if path is None:
         return None
@@ -166,12 +171,14 @@ def of(run):
     if key not in _CACHE:
         _CACHE.clear()
         _CACHE[key] = attribute(T.load(path))
-    return _CACHE[key]
+    a = _CACHE[key]
+    return None if a is None or a["devices"] < run.cell.chips else a
 
 
 def module_ms_per_iter(run, module: str, other: bool = False):
     """Device ms per iteration of ``module`` (``other``: without the
-    kernel's operations); None where the module never ran."""
+    kernel's operations), summed over the cell's chips; None where the
+    module never ran."""
     a, its = of(run), run.counts.get("iterations")
     if a is None or not its or module not in a["module_s"]:
         return None

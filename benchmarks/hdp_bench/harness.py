@@ -77,7 +77,11 @@ def note(name: str):
 class Window:
     """The measured window. With a trace directory it records a
     ``jax.profiler`` trace and the program's spans (``repro.obs``) over
-    exactly the window, and counts compilations inside it either way."""
+    exactly the window, and counts compilations inside it either way.
+
+    The profiler's Python tracer stays off: it would record every Python
+    call, slowing the host loop by how many calls the program makes. The
+    host tracer still records the annotations the readers need."""
 
     def __init__(self, trace_dir=None):
         self.trace_dir = trace_dir
@@ -96,7 +100,10 @@ class Window:
         if self.trace_dir:
             shutil.rmtree(self.trace_dir, ignore_errors=True)
             obs.enable_tracing()
-            jax.profiler.start_trace(str(self.trace_dir))
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(str(self.trace_dir),
+                                     profiler_options=options)
         self._note = note("window")
         self._note.__enter__()
         self._open = True

@@ -1,4 +1,5 @@
-"""Device time of the ``hdp_z`` kernel per streaming iteration, in ms."""
+"""Device time of the ``hdp_z`` kernel per streaming iteration, in ms:
+chip-milliseconds summed over the cell's chips."""
 
 from benchmarks.hdp_bench.readers import kernel_s
 

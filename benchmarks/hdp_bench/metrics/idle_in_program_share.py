@@ -1,6 +1,7 @@
 """Device-idle time of the window under a ``repro.*`` program span on the
-thread that holds the window, over the window, in %. The log gets the
-idle seconds per innermost span, the ten largest."""
+thread that holds the window, over the window, in %: the mean over the
+cell's chips. The log gets the idle seconds per innermost span, the ten
+largest."""
 
 import sys
 

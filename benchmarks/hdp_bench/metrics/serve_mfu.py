@@ -1,6 +1,6 @@
-"""Least time of the useful work of the requests answered in the window
-(``work.foldin_request``) over the host time of the window's engine
-steps, in %."""
+"""Least time on the cell's chips of the useful work of the requests
+answered in the window (``work.foldin_request``) over the host time of
+the window's engine steps, in %."""
 
 from benchmarks.hdp_bench.readers import least_s
 

@@ -1,5 +1,6 @@
 """Device time of the Phi draw and the z-step tables (module
-``jit_phi_tables``) per streaming iteration, in ms."""
+``jit_phi_tables``) per streaming iteration, in ms: chip-milliseconds
+summed over the cell's chips."""
 
 from benchmarks.hdp_bench.attribute import module_ms_per_iter
 

@@ -1,5 +1,5 @@
-"""Least time of one streaming iteration's work (``work.iteration``)
-over the measured time per iteration, in %."""
+"""Least time of one streaming iteration's work (``work.iteration``) on
+the cell's chips over the measured time per iteration, in %."""
 
 from benchmarks.hdp_bench.readers import least_s
 

@@ -13,12 +13,15 @@ recorded on the chip and committed with the tests.
   window         the harness's ``hdp_bench.window`` annotation on the
                  host; without it, the span of the device events
   busy           the union of operation intervals inside the window,
-                 averaged over the device planes
+                 a mean over the device planes
   op names       the operation's own name, before `` = ``, after the
                  short name of its module: ``jit_local:hdp_z.1``
   kernel time    the summed duration of operations whose own name is the
                  kernel's name (``hdp_z``, the ``pallas_call`` name) or
-                 that name with a ``.<n>`` suffix
+                 that name with a ``.<n>`` suffix: chip-seconds, summed
+                 over the device planes
+  devices        the device planes with an operation; the readers take
+                 nothing from a trace with fewer than the cell's chips
   idle gaps      the stretches of the window with no operation on a
                  device, each named by the innermost other harness
                  annotation (``hdp_bench.*``) that covers its middle
@@ -118,10 +121,12 @@ def _clip(intervals, lo, hi):
 def reduce(planes: list, kernels=("hdp_z",), top: int = 10) -> dict:
     """Busy, idle and kernel seconds over the traced window.
 
-    Returns ``window_s``, ``busy_s`` (mean over devices), ``devices``,
-    ``kernel_s`` and ``kernel_events`` per kernel name (summed over
-    devices), ``device_ops`` (the ``top`` operations by total time) and
-    ``idle_gaps`` (the ``top`` longest gaps, named by host activity).
+    Returns ``window_s``, ``busy_s`` (mean over devices), ``devices``
+    (device planes with an operation), ``kernel_s`` and
+    ``kernel_events`` per kernel name (summed over devices),
+    ``device_ops`` (the ``top`` operations by total time, summed over
+    devices) and ``idle_gaps`` (the ``top`` longest gaps of any device,
+    named by host activity).
     ``None`` when the trace holds no device operation.
     """
     devs = device_ops(planes)

@@ -33,6 +33,14 @@ and besides
                     chain key is not the reference's after as many
                     iterations: a window iteration that hands back its
                     state shows here
+  lanes_missing     the cell's chips less the number of them on which
+                    the program placed its sweep lanes: lanes stacked on
+                    one chip show here
+
+The sampler sweeps one lane per chip of the cell (``build_streaming``'s
+``devices``). The lanes' chain is bitwise the one-lane chain, so the
+reference is the same at every chip count; a lane's delta left out of
+the exchange between chips shows in ``n_recount_mismatch``.
 
 The draws, the recounts and the keys are exact (limit 0). The sweep's
 limit is the traffic's ``z_mismatch_share_limit`` and psi's its
@@ -61,11 +69,12 @@ def planted_corpus(cfg: dict, seed: int):
     return gen.pack_rows(docs, cfg["max_len"])
 
 
-def build(cfg: dict, seed: int, phase=lambda name: None):
-    """The program's stream over the planted corpus, its start state
-    (``StreamingState`` with the planted z slabs and n counted from them;
-    psi proportional to the planted topic sizes plus gamma / K), and the
-    planted z as the blocks' padded rows."""
+def build(cfg: dict, seed: int, phase=lambda name: None, chips: int = 1):
+    """The program's stream over the planted corpus, sweeping ``chips``
+    lanes, its start state (``StreamingState`` with the planted z slabs
+    and n counted from them; psi proportional to the planted topic sizes
+    plus gamma / K), and the planted z as the blocks' padded rows, in the
+    store's row order (blocks padded to a row count the lanes divide)."""
     import jax
     import jax.numpy as jnp
     from repro.core.streaming import StreamingState
@@ -85,7 +94,8 @@ def build(cfg: dict, seed: int, phase=lambda name: None):
     # the sampler reorders rows by length; the same deterministic order
     # applied to the planted z keeps every token with its topic
     z = shard_balanced(Corpus(z, mask, v), 1).tokens
-    stream = build_streaming(corpus, sh, block_docs=cfg["block_docs"])
+    stream = build_streaming(corpus, sh, block_docs=cfg["block_docs"],
+                             devices=chips)
     phase("sampler")
     store = stream.store
     db, nb, ll = store.block_docs, store.num_blocks, store.max_len
@@ -134,6 +144,14 @@ def _taken(state, z=None) -> dict:
     return {"z": z.reshape(-1, z.shape[-1]), "n": state.n,
             "varphi": state.varphi, "psi": state.psi, "l": state.l,
             "key": state.key}
+
+
+def lanes_missing(stream, devices) -> int:
+    """``devices`` less the number of them that hold a sweep lane of
+    ``stream`` (one lane on the sampler's device when it sweeps one).
+    The lanes' jitted sweeps run where their inputs are placed, on these
+    devices; ``StreamingHDP`` keeps them in ``_lane_devices``."""
+    return len(devices) - len(set(devices) & set(stream._lane_devices))
 
 
 def _key_bad(a, b) -> int:
@@ -199,7 +217,7 @@ def run(cell, *, seed: int, seconds: float, t0: float, trace_dir=None,
     k, v, w = cfg["K"], cfg["V"], cfg["W"]
     phase = Phases()
     with note("setup"):
-        stream, state, z0 = build(cfg, seed, phase)
+        stream, state, z0 = build(cfg, seed, phase, chips=cell.chips)
         store = stream.store
         before1 = _taken(state, z0)
         with note("iteration"):
@@ -219,7 +237,9 @@ def run(cell, *, seed: int, seconds: float, t0: float, trace_dir=None,
             if time.perf_counter() - win.t0 >= seconds:
                 break
         window_s = win.close()
-    peak = memory_peak(jax.devices()[:cell.chips])
+    devices = jax.devices()[:cell.chips]
+    peak = memory_peak(devices)
+    missing = lanes_missing(stream, devices)
     spans, span_args = win.spans() if trace_dir else ({}, [])
     reduced = win.reduce() if trace_dir else None
     # the check iteration: the same object and call, after the window
@@ -250,7 +270,8 @@ def run(cell, *, seed: int, seconds: float, t0: float, trace_dir=None,
     key_bad = (_key_bad(end["key"], key_end)
                + _key_bad(after_chk["key"], R.key_chain(key_end, 1)))
     checks += [("n_recount_mismatch.end", n_end_bad, 0),
-               ("key_mismatch", key_bad, 0)]
+               ("key_mismatch", key_bad, 0),
+               ("lanes_missing", missing, 0)]
     ref_s = time.perf_counter() - t_ref
     counts = {"iterations": its, "window_s": window_s, "walls_s": walls,
               "live_tokens": live, "positions": nb * db * ll,

@@ -82,8 +82,9 @@ def _run(cell, monkeypatch, tmp_path, the_planes, counts):
     monkeypatch.setattr(A.T, "load", lambda path: the_planes)
     A._CACHE.clear()
     spans = {"engine.retire_wait": 0.004}
-    return SimpleNamespace(cell=SimpleNamespace(name=cell), trace={"x": 1},
-                           counts=dict(counts, window_s=0.1), spans=spans)
+    return SimpleNamespace(cell=SimpleNamespace(name=cell, chips=1),
+                           trace={"x": 1}, counts=dict(counts, window_s=0.1),
+                           spans=spans)
 
 
 def _readers(cell):
@@ -91,9 +92,9 @@ def _readers(cell):
 
 
 def test_train_readers(monkeypatch, tmp_path, capsys):
-    run = _run("train.pubmed.short", monkeypatch, tmp_path, planes(),
+    run = _run("train.neurips.long", monkeypatch, tmp_path, planes(),
                {"iterations": 2})
-    r = _readers("train.pubmed.short")
+    r = _readers("train.neurips.long")
     assert r["tables_ms_per_iter.train"](run) == pytest.approx(10.0)
     assert r["delta_n_ms_per_iter.train"](run) == pytest.approx(6.0)
     assert r["idle_in_program_share.train"](run) == pytest.approx(18.0)
@@ -117,10 +118,10 @@ def test_readers_read_nothing_from_a_program_without_names(monkeypatch,
         "jit_phi_tables", "jit__phi_tables").replace(
         "jit_z_block", "jit_local"), s, d) for e, s, d in evs])
         for ln, evs in lines]) for n, lines in planes()]
-    run = _run("train.pubmed.short", monkeypatch, tmp_path, old,
+    run = _run("train.neurips.long", monkeypatch, tmp_path, old,
                {"iterations": 2})
     run.spans = {}
-    r = _readers("train.pubmed.short")
+    r = _readers("train.neurips.long")
     for name in ("tables_ms_per_iter.train", "delta_n_ms_per_iter.train",
                  "idle_in_program_share.train"):
         assert r[name](run) is None, name
@@ -129,8 +130,9 @@ def test_readers_read_nothing_from_a_program_without_names(monkeypatch,
 
 
 def test_untraced_run_reads_nothing():
-    run = SimpleNamespace(cell=SimpleNamespace(name="train.pubmed.short"),
-                          trace=None, counts={"iterations": 2}, spans={})
+    run = SimpleNamespace(
+        cell=SimpleNamespace(name="train.neurips.long", chips=1),
+        trace=None, counts={"iterations": 2}, spans={})
     assert A.of(run) is None
     assert A.module_ms_per_iter(run, "jit_phi_tables") is None
 
@@ -151,9 +153,10 @@ def test_recorded_chip_trace_with_program_names(monkeypatch):
         "jit_z_block"]
     monkeypatch.setattr(A, "trace_file", lambda run: path)
     A._CACHE.clear()
-    run = SimpleNamespace(cell=SimpleNamespace(name="train.pubmed.short"),
-                          trace={"x": 1}, counts={"iterations": 1})
-    r = _readers("train.pubmed.short")
+    run = SimpleNamespace(
+        cell=SimpleNamespace(name="train.neurips.long", chips=1),
+        trace={"x": 1}, counts={"iterations": 1})
+    r = _readers("train.neurips.long")
     assert r["tables_ms_per_iter.train"](run) > 0
     assert r["delta_n_ms_per_iter.train"](run) > 0
     assert 0 < r["idle_in_program_share.train"](run) < 100

@@ -2,8 +2,10 @@
 control (the program's compact bfloat16/int16 tables) fails, and so does
 a run whose timed path is broken underneath.
 
-The cell has one chip, so the fault of an exchange between chips left
-out cannot occur in it.
+These cells have one chip. The faults of the exchange between chips, a
+lane's delta left out of the merge and the lanes stacked on one device,
+are run on the four-lane cell ``tiny.train.lanes4`` in
+``test_bench_lanes.py``.
 """
 
 import time
